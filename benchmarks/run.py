@@ -45,6 +45,8 @@ def main(argv=None) -> None:
                     help="write per-bench status to this JSON file")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     statuses = {}
     failures = []
     for name, fn in BENCHES:

@@ -185,8 +185,7 @@ def _rule_tuning_cache_hit(art: StepArtifacts) -> list[Finding]:
         locus=f"stats delta: {d}")]
 
 
-# kernel-name fragment every fused-decode pallas_call carries (the kv16
-# closure is named fused_decode_kernel_kv16 for exactly this match)
+# the name every fused-decode pallas_call is given (kernels/decode_fused.py)
 _FUSED_KERNEL_NAME = "fused_decode_kernel"
 # primitives that round-trip through the host mid-step (a decode step
 # containing one cannot be a single async device dispatch)
@@ -209,12 +208,12 @@ def _rule_fused_decode_single_dispatch(art: StepArtifacts) -> list[Finding]:
     for eqn in jaxpr_walker.iter_eqns(art.jaxpr):
         name = eqn.primitive.name
         if name == "pallas_call":
-            info = str(eqn.params.get("name_and_src_info", ""))
+            info = eqn.params.get("name") or ""
             if _FUSED_KERNEL_NAME in info:
                 fused += 1
             else:
                 other += 1
-                other_names.append(info.split(" at ")[0] or "<unnamed>")
+                other_names.append(info or "<unnamed>")
         elif name in _HOST_SYNC_PRIMS:
             syncs.append(name)
     out = []

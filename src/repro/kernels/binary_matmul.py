@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _kernel(a_ref, w_ref, alpha_ref, out_ref, acc_ref, *, k_total: int, n_k: int):
     kk = pl.program_id(2)
@@ -33,9 +31,14 @@ def _kernel(a_ref, w_ref, alpha_ref, out_ref, acc_ref, *, k_total: int, n_k: int
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[...]                                    # (bm, bkw) int32
-    w = w_ref[...]                                    # (bn, bkw) int32
-    x = jax.lax.bitwise_xor(a[:, None, :], w[None, :, :])   # (bm, bn, bkw)
-    mismatch = jnp.sum(jax.lax.population_count(x), axis=-1, dtype=jnp.int32)
+    wt = w_ref[...].T                                 # (bkw, bn) int32
+    # one word column at a time: (bm, 1) XOR (1, bn) is a lane-dense
+    # (bm, bn) tile, where a whole (bm, bn, bkw) XOR tensor pads bkw to 128
+    # lanes in VMEM and compiles ~20x slower at serving widths
+    mismatch = jnp.zeros(acc_ref.shape, jnp.int32)
+    for i in range(a.shape[1]):
+        mismatch += jax.lax.population_count(
+            jax.lax.bitwise_xor(a[:, i:i + 1], wt[i:i + 1, :]))
     acc_ref[...] += mismatch
 
     @pl.when(kk == n_k - 1)
@@ -79,7 +82,7 @@ def binary_matmul(a_packed, wt_packed, alpha=None, *, k: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, out_ref,
             m_ref, l_ref, acc_ref, *, chunk: int, n_chunks: int, dh: int):
@@ -90,7 +88,7 @@ def decode_attention(q, k_codes, k_scale, v_codes, v_scale, pos, *,
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos2, q, k_codes, k_scale, v_codes, v_scale)

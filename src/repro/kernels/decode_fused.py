@@ -18,16 +18,18 @@ covers both, and the grid's slot dimension runs over **live slots only**:
     softmax scratch carried across iterations — sequence-parallel partial
     accumulation (the split-K of flash decode), with the per-block
     ``pl.when(j * bs <= pos)`` live guard so blocks wholly beyond ``pos``
-    skip dequant and both dots.
+    skip dequant and both dots.  Each step moves one pool block for all KV
+    heads, laid out as in :mod:`repro.kernels.paged_attention`.
   * the output projection is folded into the final block step: attention is
-    linear in the value heads, so each KV-head grid step contributes
-    ``attn_heads(ki) @ wo[ki·G·Dh : (ki+1)·G·Dh]`` and accumulates into the
-    same (1, D) output block (the KV dimension is marked "arbitrary" so the
-    revisited output block is legal).
+    linear in the value heads, so each query head ``h`` contributes
+    ``attn_h @ wo[h·Dh : (h+1)·Dh]`` to the slot's (1, D) output.  ``wo``'s
+    block index never changes, so it is fetched once per call, not once per
+    slot.
 
-The kernel computes the float-weight projection (``wo`` dense f32) — the
-quantized-``wo`` epilogue (per-row activation requantization) stays in the
-engine's composition fallback so its numerics never fork from ``qmatmul``.
+The kernel computes the float-weight projection (``wo`` upcast to f32 in
+VMEM) — the quantized-``wo`` epilogue (per-row activation requantization)
+stays in the engine's composition fallback so its numerics never fork from
+``qmatmul``.
 
 Layout (per device, post-sharding):
   q          : (B, KV, G, Dh)    padded batch of current-token queries
@@ -36,8 +38,10 @@ Layout (per device, post-sharding):
   page_table : (B, n_blocks)     int32 (scalar prefetch)
   pos        : (B,)              int32 (scalar prefetch)
   slot_map   : (L,)              int32 live slot ids (scalar prefetch)
-  wo         : (KV*G*Dh, D)      f32 output-projection weight
-  out        : (L, D)            f32, compact over live slots
+  wo         : (KV*G*Dh, D)      float output-projection weight
+  out        : (L, D)            f32, compact over live slots (the kernel
+                                 writes (L, 1, D): a (1, D) block over L
+                                 rows would have a unit second-minor dim)
 """
 from __future__ import annotations
 
@@ -48,69 +52,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.packing import unpack_nibbles
-
-from ._compat import CompilerParams
+from .paged_attention import (attend_block, heads_view,
+                              init_softmax_scratch)
 
 
 def fused_decode_kernel(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, ks_ref,
                         vp_ref, vs_ref, wo_ref, out_ref, m_ref, l_ref,
-                        acc_ref, *, bs: int, n_blocks: int, dh: int,
+                        acc_ref, *, bs: int, n_blocks: int, kv: int, dh: int,
                         kv_bits: int):
     li = pl.program_id(0)
-    ki = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     slot = sm_ref[li]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def dequant(codes_ref, scale_ref):
-        c = codes_ref[0, :, 0]                               # (bs, Dh_store)
-        if kv_bits == 4:
-            c = unpack_nibbles(c)
-        x = c.astype(jnp.float32)
-        if scale_ref is not None:
-            x = x * scale_ref[0, :, 0]
-        return x                                             # (bs, Dh)
+        init_softmax_scratch(m_ref, l_ref, acc_ref)
 
     # per-block live guard: a fully-dead block's online-softmax update is
     # the identity, so skipping it is bit-identical (see paged_attention)
     @pl.when(j * bs <= pos_ref[slot])
     def _live_block():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, Dh)
-        k = dequant(kp_ref, ks_ref)
-        s = jnp.dot(q, k.T) / (dh ** 0.5)                    # (G, bs)
-        idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        mask = idx <= pos_ref[slot]                          # (1, bs)
-        s_masked = jnp.where(mask, s, -1e30)
+        attend_block(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, m_ref, l_ref,
+                     acc_ref, j=j, pos=pos_ref[slot], bs=bs, kv=kv, dh=dh,
+                     kv_bits=kv_bits)
 
-        m_prev = m_ref[...]                                  # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_masked, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # (G, bs)
-        corr = jnp.exp(m_prev - m_new)                       # (G, 1)
-        v = dequant(vp_ref, vs_ref)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v)
-        m_ref[...] = m_new
-
-    # epilogue: project this KV head group's attention output through its
-    # wo row block and accumulate into the slot's (1, D) output
+    # epilogue: project every query head's attention output, rounded to
+    # the model dtype as the unfused layer rounds it, through its wo row
+    # block into the slot's (1, D) output
     @pl.when(j == n_blocks - 1)
     def _project():
-        attn = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)   # (G, Dh)
-        contrib = jnp.dot(attn.reshape(1, -1), wo_ref[...])    # (1, D)
-
-        @pl.when(ki == 0)
-        def _set():
-            out_ref[...] = contrib.astype(out_ref.dtype)
-
-        @pl.when(ki != 0)
-        def _acc():
-            out_ref[...] = out_ref[...] + contrib.astype(out_ref.dtype)
+        out = jnp.zeros(out_ref.shape[1:], jnp.float32)
+        g = acc_ref.shape[1]
+        for h in range(kv):
+            attn = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(
+                q_ref.dtype).astype(jnp.float32)             # (G, Dh)
+            for gi in range(g):
+                row = (h * g + gi) * dh
+                w = wo_ref[row:row + dh, :].astype(jnp.float32)  # (Dh, D)
+                out = out + jnp.dot(attn[gi:gi + 1], w)
+        out_ref[0] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_bits", "interpret"))
@@ -134,61 +114,56 @@ def fused_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
     pt = page_table.astype(jnp.int32)
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     sm = slot_map.astype(jnp.int32)
-    wo = wo.astype(jnp.float32)
 
-    dh_store = k_pool.shape[-1]
     kern = functools.partial(fused_decode_kernel, bs=bs, n_blocks=n_blocks,
-                             dh=dh, kv_bits=kv_bits)
+                             kv=kv, dh=dh, kv_bits=kv_bits)
     if not has_scale:
-        # named fused_decode_kernel_* so the fused_decode_single_dispatch
-        # audit rule recognizes the dispatch by its jaxpr kernel name
-        def fused_decode_kernel_kv16(sm_ref, pt_ref, pos_ref, q_ref, kp_ref,
-                                     vp_ref, wo_ref, out_ref, m_ref, l_ref,
-                                     acc_ref):
+        # kv_bits=16: no scale operands; close the kernel over None refs
+        def kern_ns(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, vp_ref, wo_ref,
+                    out_ref, m_ref, l_ref, acc_ref):
             return fused_decode_kernel(
                 sm_ref, pt_ref, pos_ref, q_ref, kp_ref, None, vp_ref, None,
                 wo_ref, out_ref, m_ref, l_ref, acc_ref, bs=bs,
-                n_blocks=n_blocks, dh=dh, kv_bits=kv_bits)
-        kern = fused_decode_kernel_kv16
+                n_blocks=n_blocks, kv=kv, dh=dh, kv_bits=kv_bits)
+        kern = kern_ns
 
-    pool_spec = pl.BlockSpec(
-        (1, bs, 1, dh_store),
-        lambda li, ki, j, sm, pt, pos: (pt[sm[li], j], 0, ki, 0))
-    scale_spec = pl.BlockSpec(
-        (1, bs, 1, 1),
-        lambda li, ki, j, sm, pt, pos: (pt[sm[li], j], 0, ki, 0))
+    block_map = lambda li, j, sm, pt, pos: (pt[sm[li], j], 0, 0)
+    pool_spec = pl.BlockSpec((1, bs, kv * k_pool.shape[-1]), block_map)
+    scale_spec = pl.BlockSpec((1, bs, kv), block_map)
     q_spec = pl.BlockSpec(
-        (1, 1, g, dh), lambda li, ki, j, sm, pt, pos: (sm[li], ki, 0, 0))
+        (1, kv, g, dh), lambda li, j, sm, pt, pos: (sm[li], 0, 0, 0))
     wo_spec = pl.BlockSpec(
-        (g * dh, d_out), lambda li, ki, j, sm, pt, pos: (ki, 0))
+        (kv * g * dh, d_out), lambda li, j, sm, pt, pos: (0, 0))
     if has_scale:
         in_specs = [q_spec, pool_spec, scale_spec, pool_spec, scale_spec,
                     wo_spec]
-        operands = (q, k_pool, k_scale, v_pool, v_scale, wo)
+        pooled = (k_pool, k_scale, v_pool, v_scale)
     else:
         in_specs = [q_spec, pool_spec, pool_spec, wo_spec]
-        operands = (q, k_pool, v_pool, wo)
+        pooled = (k_pool, v_pool)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_live, kv, n_blocks),
+        grid=(n_live, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, d_out),
-                               lambda li, ki, j, sm, pt, pos: (li, 0)),
-        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, dh), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, d_out),
+                               lambda li, j, sm, pt, pos: (li, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),
+                        pltpu.VMEM((kv, g, 1), jnp.float32),
+                        pltpu.VMEM((kv, g, dh), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_live, d_out), jnp.float32),
-        compiler_params=CompilerParams(
-            # the KV-head dim revisits (accumulates into) the output block,
-            # so it must stay sequential ("arbitrary"), like the block dim
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        # the fused_decode_single_dispatch audit rule finds the dispatch in
+        # the jaxpr by this name
+        name="fused_decode_kernel",
+        out_shape=jax.ShapeDtypeStruct((n_live, 1, d_out), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(sm, pt, pos_b, *operands)
+    )(sm, pt, pos_b, q, *(heads_view(x) for x in pooled), wo)
+    return out[:, 0]
 
 
 def fused_decode_ref(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,

@@ -806,11 +806,12 @@ def _fused_decode_pallas(q, k, ks, v, vs, extras, *, kv_bits, dtype, block,
     fork numerics from the registry matmul the rest of the model uses)."""
     page_table, pos, slot_map, wo_p, pcfg = extras
     if not _wo_is_float(wo_p, pcfg):
-        from .paged_attention import paged_attention
+        attend = resolve_attention(ATTN_PAGED, kv_bits, BACKEND_PALLAS)
         ql = q[slot_map]
-        attn = paged_attention(ql, k, ks, v, vs, page_table[slot_map],
-                               jnp.asarray(pos)[slot_map], kv_bits=kv_bits,
-                               interpret=interpret).astype(dtype)
+        attn = attend(ql, k, ks, v, vs,
+                      (page_table[slot_map], jnp.asarray(pos)[slot_map]),
+                      kv_bits=kv_bits, dtype=dtype, block=None,
+                      interpret=interpret)
         flat = attn.reshape(ql.shape[0], 1, -1)
         return _project_wo(flat, wo_p, pcfg, dtype)
     from .decode_fused import fused_decode

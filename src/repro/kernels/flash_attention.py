@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
             bq: int, bk: int, g: int, dh: int, n_k: int,
@@ -99,7 +97,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         scratch_shapes=[pltpu.VMEM((bq * g, 1), jnp.float32),
                         pltpu.VMEM((bq * g, 1), jnp.float32),
                         pltpu.VMEM((bq * g, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

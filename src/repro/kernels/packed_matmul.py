@@ -16,8 +16,9 @@ Layout:
   out       : (M, N)   float32/bf16
 
 Grid: (M/bm, N/bn, K/bk) with K innermost; int32 (or f32) VMEM scratch
-accumulator; MXU-aligned tiles (bm, bn multiples of 128; bk multiple of the
-pack word: bk*bits % 32 == 0).
+accumulator; tiles legal for Mosaic's (8, 128) block tiling
+(``tuning._valid_block``: bm a multiple of 8, bn a multiple of 128 or all of
+N, bk all of K or a multiple of 128 pack words).
 """
 from __future__ import annotations
 
@@ -28,21 +29,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _unpack_block(words, bits: int):
-    """int32 words (bn, bkw) -> int8 codes (bn, bkw * 32/bits), sign-extended."""
+    """int32 words (bn, bkw) -> int8 codes (bkw * 32/bits, bn), sign-extended
+    and TRANSPOSED (K on sublanes): the fields of a word expand along a
+    sublane axis, which merges into K without the 8-16x lane padding of a
+    (bn, bkw, 32/bits) expansion."""
     n = 32 // bits
     mask = (1 << bits) - 1
-    w = words.astype(jnp.uint32)
-    shifts = jnp.arange(n, dtype=jnp.uint32) * bits
-    fields = (w[..., None] >> shifts[None, None, :]) & mask          # (bn, bkw, n)
-    fields = fields.astype(jnp.int32)
+    w = words.T.astype(jnp.uint32)                                    # (bkw, bn)
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, n, 1), 1) * bits
+    fields = ((w[:, None, :] >> shifts) & mask).astype(jnp.int32)     # (bkw, n, bn)
     if bits > 1:
         sign_bit = 1 << (bits - 1)
         fields = jnp.where(fields >= sign_bit, fields - (1 << bits), fields)
-    return fields.reshape(words.shape[0], -1).astype(jnp.int8)
+    return fields.reshape(-1, words.shape[0]).astype(jnp.int8)
 
 
 def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *,
@@ -53,17 +54,18 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    wt = _unpack_block(w_ref[...], bits)                              # (bn, bk) int8
+    w = _unpack_block(w_ref[...], bits)                               # (bk, bn) int8
     if int_path:
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...], wt,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        # integer dots have one precision: pin it, or a global
+        # jax_default_matmul_precision="highest" asks Mosaic for an fp32
+        # contraction of int8 operands, which it refuses
+        acc_ref[...] += jnp.dot(x_ref[...], w,
+                                precision=jax.lax.Precision.DEFAULT,
+                                preferred_element_type=jnp.int32)
     else:
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), wt.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32),
+                                w.astype(jnp.float32),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -115,7 +117,7 @@ def packed_matmul(x, wt_packed, scale, bias=None, *, bits: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

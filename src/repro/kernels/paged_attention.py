@@ -25,11 +25,17 @@ Layout (per device, post-sharding):
   pos        : (B,)              int32 per-sequence positions (mask: s <= pos)
   out        : (B, KV, G, Dh)    f32
 
-Grid: (B, KV, n_blocks), blocks innermost; scratch m/l/acc carried across a
-sequence's blocks (online softmax).  Blocks wholly beyond ``pos`` still DMA
-(their page-table entries point at the reserved null block 0) but skip the
-dot/softmax update entirely (``pl.when(j * bs <= pos)``) — bit-identical to
-masking, since a fully-masked block's update is the identity.
+Grid: (B, n_blocks), blocks innermost; one grid step moves one pool block
+for ALL KV heads.  The kernel sees the pool through a free reshape,
+``(NB, bs, KV*Dh')`` codes and ``(NB, bs, KV)`` scales, so every block's
+last two dims span whole array dims — Mosaic tiles them in (8, 128) units
+and refuses a block of one head out of KV (a unit second-minor dim).  Each
+head is a static lane slice of the block.  Scratch m/l/acc, (KV, G, ·),
+is carried across a sequence's blocks (online softmax).  Blocks wholly
+beyond ``pos`` still DMA (their page-table entries point at the reserved
+null block 0) but skip the dot/softmax update entirely
+(``pl.when(j * bs <= pos)``) — bit-identical to masking, since a
+fully-masked block's update is the identity.
 """
 from __future__ import annotations
 
@@ -42,29 +48,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_nibbles
 
-from ._compat import CompilerParams
 
 
-def _kernel(pt_ref, pos_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref, out_ref,
-            m_ref, l_ref, acc_ref, *, bs: int, n_blocks: int, dh: int,
-            kv_bits: int):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+def heads_view(leaf):
+    """``(NB, bs, KV, Dh')`` pool leaf -> ``(NB, bs, KV*Dh')`` (a free
+    reshape): the layout the paged kernels block over."""
+    return leaf.reshape(*leaf.shape[:2], -1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def dequant(codes_ref, scale_ref):
-        c = codes_ref[0, :, 0]                               # (bs, Dh_store)
+def init_softmax_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def attend_block(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, m_ref, l_ref,
+                 acc_ref, *, j, pos, bs: int, kv: int, dh: int,
+                 kv_bits: int):
+    """Online-softmax update of every KV head's scratch with pool block
+    ``j`` (positions ``j*bs ..``, masked past ``pos``).  ``q_ref[0, h]`` is
+    head group ``h``'s (G, Dh) queries; the pool refs hold one block of all
+    heads, head ``h`` in lanes ``[h*Dh', (h+1)*Dh')``.
+
+    Dequantized K/V are rounded to the queries' (the model's) dtype, as the
+    model's own dequant does (``layers._kv_dequant``): with a bf16 model the
+    reference attends over bf16 K/V, and skipping that rounding shifts every
+    score by up to 2^-9 relative."""
+    dh_store = kp_ref.shape[-1] // kv
+
+    def dequant(codes_ref, scale_ref, h):
+        c = codes_ref[0, :, h * dh_store:(h + 1) * dh_store]  # (bs, Dh')
         if kv_bits == 4:
             c = unpack_nibbles(c)
         x = c.astype(jnp.float32)
         if scale_ref is not None:
-            x = x * scale_ref[0, :, 0]
-        return x                                             # (bs, Dh)
+            x = (x * scale_ref[0, :, h:h + 1]).astype(q_ref.dtype)
+        return x.astype(jnp.float32)                         # (bs, Dh)
+
+    idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    mask = idx <= pos                                        # (1, bs)
+    for h in range(kv):
+        q = q_ref[0, h].astype(jnp.float32)                  # (G, Dh)
+        k = dequant(kp_ref, ks_ref, h)
+        s = jnp.dot(q, k.T) / (dh ** 0.5)                    # (G, bs)
+        s_masked = jnp.where(mask, s, -1e30)
+
+        m_prev = m_ref[h]                                    # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s_masked, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # (G, bs)
+        corr = jnp.exp(m_prev - m_new)                       # (G, 1)
+        v = dequant(vp_ref, vs_ref, h)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jnp.dot(p, v)
+        m_ref[h] = m_new
+
+
+def _kernel(pt_ref, pos_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref, out_ref,
+            m_ref, l_ref, acc_ref, *, bs: int, n_blocks: int, kv: int,
+            dh: int, kv_bits: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        init_softmax_scratch(m_ref, l_ref, acc_ref)
 
     # Blocks whose first position is already past ``pos`` contribute exact
     # zeros through the mask (p=0, m_new=m_prev, corr=1), so skipping the
@@ -72,26 +119,14 @@ def _kernel(pt_ref, pos_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref, out_ref,
     # only their (null-block) DMA, not dequant + two dots per block.
     @pl.when(j * bs <= pos_ref[b])
     def _live_block():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, Dh)
-        k = dequant(kp_ref, ks_ref)
-        s = jnp.dot(q, k.T) / (dh ** 0.5)                    # (G, bs)
-        idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        mask = idx <= pos_ref[b]                             # (1, bs)
-        s_masked = jnp.where(mask, s, -1e30)
-
-        m_prev = m_ref[...]                                  # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_masked, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # (G, bs)
-        corr = jnp.exp(m_prev - m_new)                       # (G, 1)
-        v = dequant(vp_ref, vs_ref)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v)
-        m_ref[...] = m_new
+        attend_block(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, m_ref, l_ref,
+                     acc_ref, j=j, pos=pos_ref[b], bs=bs, kv=kv, dh=dh,
+                     kv_bits=kv_bits)
 
     @pl.when(j == n_blocks - 1)
     def _done():
-        out_ref[0, 0] = (acc_ref[...] /
-                         jnp.maximum(l_ref[...], 1e-30)).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[...] /
+                      jnp.maximum(l_ref[...], 1e-30)).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -104,15 +139,14 @@ def paged_attention(q, k_pool, k_scale, v_pool, v_scale, page_table, pos, *,
     ``pos`` is scalar or (B,) per-sequence current positions.
     """
     b, kv, g, dh = q.shape
-    nb_pool, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[1]
     n_blocks = page_table.shape[1]
     has_scale = k_scale is not None
     assert has_scale == (kv_bits < 16), (kv_bits, has_scale)
     pt = page_table.astype(jnp.int32)
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
 
-    dh_store = k_pool.shape[-1]
-    kern = functools.partial(_kernel, bs=bs, n_blocks=n_blocks, dh=dh,
+    kern = functools.partial(_kernel, bs=bs, n_blocks=n_blocks, kv=kv, dh=dh,
                              kv_bits=kv_bits)
     if not has_scale:
         # kv_bits=16: no scale operands; close the kernel over None refs
@@ -120,14 +154,13 @@ def paged_attention(q, k_pool, k_scale, v_pool, v_scale, page_table, pos, *,
                     m_ref, l_ref, acc_ref):
             return _kernel(pt_ref, pos_ref, q_ref, kp_ref, None, vp_ref, None,
                            out_ref, m_ref, l_ref, acc_ref, bs=bs,
-                           n_blocks=n_blocks, dh=dh, kv_bits=kv_bits)
+                           n_blocks=n_blocks, kv=kv, dh=dh, kv_bits=kv_bits)
         kern = kern_ns
 
-    pool_spec = pl.BlockSpec((1, bs, 1, dh_store),
-                             lambda bi, ki, j, pt, pos: (pt[bi, j], 0, ki, 0))
-    scale_spec = pl.BlockSpec((1, bs, 1, 1),
-                              lambda bi, ki, j, pt, pos: (pt[bi, j], 0, ki, 0))
-    q_spec = pl.BlockSpec((1, 1, g, dh), lambda bi, ki, j, pt, pos: (bi, ki, 0, 0))
+    block_map = lambda bi, j, pt, pos: (pt[bi, j], 0, 0)
+    pool_spec = pl.BlockSpec((1, bs, kv * k_pool.shape[-1]), block_map)
+    scale_spec = pl.BlockSpec((1, bs, kv), block_map)
+    q_spec = pl.BlockSpec((1, kv, g, dh), lambda bi, j, pt, pos: (bi, 0, 0, 0))
     in_specs = [q_spec, pool_spec, scale_spec, pool_spec, scale_spec] \
         if has_scale else [q_spec, pool_spec, pool_spec]
     operands = (q, k_pool, k_scale, v_pool, v_scale) if has_scale \
@@ -135,22 +168,22 @@ def paged_attention(q, k_pool, k_scale, v_pool, v_scale, page_table, pos, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, n_blocks),
+        grid=(b, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, ki, j, pt, pos: (bi, ki, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, dh), jnp.float32)],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),
+                        pltpu.VMEM((kv, g, 1), jnp.float32),
+                        pltpu.VMEM((kv, g, dh), jnp.float32)],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
+        name="paged_attention",
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pt, pos_b, *operands)
+    )(pt, pos_b, q, *(heads_view(x) for x in operands[1:]))
 
 
 def gather_pool(pool_leaf, page_table):

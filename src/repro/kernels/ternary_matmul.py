@@ -26,20 +26,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _decode_ternary(words):
-    """(bn, bkw) int32 -> (bn, bkw*16) int8 in {-1, 0, +1}.
+    """(bn, bkw) int32 -> (bkw*16, bn) int8 in {-1, 0, +1}: the decoded
+    block TRANSPOSED, K on sublanes.  The 16 fields of a word then expand
+    along a sublane axis, (bkw, 16, bn), which merges into K for free; the
+    untransposed expansion (bn, bkw, 16) pads 16 lanes to 128 in VMEM and
+    does not fit at serving widths.
 
     2-bit two's complement: 00 -> 0, 01 -> +1, 11 -> -1 (10 unused/-2 guarded
     upstream by the quantizer)."""
-    w = words.astype(jnp.uint32)
-    shifts = jnp.arange(16, dtype=jnp.uint32) * 2
-    f = (w[..., None] >> shifts[None, None, :]) & 0x3          # (bn, bkw, 16)
-    f = f.astype(jnp.int32)
+    w = words.T.astype(jnp.uint32)                             # (bkw, bn)
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 16, 1), 1) * 2
+    f = ((w[:, None, :] >> shifts) & 0x3).astype(jnp.int32)    # (bkw, 16, bn)
     f = jnp.where(f >= 2, f - 4, f)                            # sign-extend
-    return f.reshape(words.shape[0], -1).astype(jnp.int8)
+    return f.reshape(-1, words.shape[0]).astype(jnp.int8)
 
 
 def _kernel(x_ref, w_ref, alpha_ref, bias_ref, out_ref, acc_ref, *,
@@ -50,16 +51,18 @@ def _kernel(x_ref, w_ref, alpha_ref, bias_ref, out_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    wt = _decode_ternary(w_ref[...])                           # (bn, bk) int8
+    w = _decode_ternary(w_ref[...])                            # (bk, bn) int8
     if int_path:
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...], wt, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        # integer dots have one precision: pin it, or a global
+        # jax_default_matmul_precision="highest" asks Mosaic for an fp32
+        # contraction of int8 operands, which it refuses
+        acc_ref[...] += jnp.dot(x_ref[...], w,
+                                precision=jax.lax.Precision.DEFAULT,
+                                preferred_element_type=jnp.int32)
     else:
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), wt.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32),
+                                w.astype(jnp.float32),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(kk == n_k - 1)
     def _epilogue():
@@ -107,7 +110,7 @@ def ternary_matmul(x, wt_packed, alpha, bias=None, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -7,15 +7,17 @@ configuration knob is the Pallas tile: (bm, bn, bk) block sizes trade VMEM
 residency against grid overhead differently for a 1-bit XNOR kernel than for
 an 8-bit unpack-to-MXU kernel.  This module owns that search:
 
-  * ``candidate_blocks`` enumerates MXU-aligned tiles valid for a given
-    (M, N, K, weight_kind, w_bits) — the pack word imposes ``bk % (32/bits)``
-    and the XNOR kernel counts K in 32-bit words;
+  * ``candidate_blocks`` enumerates tiles valid for a given
+    (M, N, K, weight_kind, w_bits) — the pack word imposes ``bk % (32/bits)``,
+    the XNOR kernel counts K in 32-bit words, and Mosaic tiles the last two
+    dims of every block in (8, 128) units (see :func:`_valid_block`);
   * ``autotune`` times a caller-supplied ``measure(block)`` over the
     candidates (interpret-mode on CPU, compiled on TPU) and records the
     winner;
   * winners persist to a JSON cache (``~/.cache/repro/tuning.json``,
-    override with ``REPRO_TUNING_CACHE``) keyed by shape class, so serving
-    processes only ever *look up* — they never re-sweep.
+    override with ``REPRO_TUNING_CACHE``) keyed by device kind and shape
+    class, so serving processes only ever *look up* — they never re-sweep,
+    and a tile timed on one device kind is never served on another.
 
 ``get_block_sizes`` is the hot-path entry: cache hit returns the tuned tile,
 miss returns a safe clipped default (and counts a miss — it does NOT sweep;
@@ -155,10 +157,19 @@ def shape_class(m: int, n: int, k: int) -> tuple[int, int, int]:
     return (_pow2_bucket(m), n, k)
 
 
+def device_kind() -> str:
+    """The kind of device the process computes on (``TPU v5 lite``, ``cpu``):
+    a tile timed on one kind says nothing about another, so it is part of
+    every cache key."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
 def cache_key(kind: str, a_bits: int, w_bits: int, backend: str,
               m: int, n: int, k: int) -> str:
     mb, nn, kk = shape_class(m, n, k)
-    return f"{backend}|{kind}|a{a_bits}w{w_bits}|m{mb}n{nn}k{kk}"
+    return (f"{backend}|{device_kind()}|{kind}|a{a_bits}w{w_bits}"
+            f"|m{mb}n{nn}k{kk}")
 
 
 def _bk_align(kind: str, w_bits: int) -> int:
@@ -172,38 +183,54 @@ def _bk_align(kind: str, w_bits: int) -> int:
     return 1
 
 
+# Mosaic lays the last two dims of every block out in (8, 128) tiles: each
+# block dim there must be a multiple of its tile dim or span the whole array.
+SUBLANE, LANE = 8, 128
+
+
+def _bk_step(kind: str, w_bits: int) -> int:
+    """Smallest legal bk short of the whole K: the packed weight block
+    ``(bn, bk / codes_per_word)`` must keep a lane multiple of 128 words."""
+    return LANE * _bk_align(kind, w_bits)
+
+
 def _valid_block(m: int, n: int, k: int, kind: str, w_bits: int,
                  block: Block) -> bool:
+    """A tile the kernels accept AND Mosaic can lay out.  The blocks are
+    x ``(bm, bk)`` (XNOR: ``(bm, bk/32)`` words), weight ``(bn, bkw)``,
+    scale ``(1, bn)`` and out ``(bm, bn)``, so: bm a multiple of 8 (the
+    engine pads M up to it), bn a multiple of 128 dividing N or all of N,
+    and bk all of K or a multiple of ``_bk_step`` dividing K."""
     bm, bn, bk = block
-    align = _bk_align(kind, w_bits)
-    return (bn <= n and n % bn == 0
-            and bk <= k and k % bk == 0 and bk % align == 0
-            and bm <= max(256, _pow2_bucket(m)))
+    return (bm % SUBLANE == 0 and bm <= max(256, _pow2_bucket(m))
+            and (bn == n or (bn % LANE == 0 and n % bn == 0))
+            and (bk == k or (bk % _bk_step(kind, w_bits) == 0
+                             and k % bk == 0)))
 
 
 def fallback_block(m: int, n: int, k: int, kind: str, w_bits: int) -> Block:
-    """The hand-wired default (what ops.py used to hard-code), clipped so it
-    is valid for this shape."""
+    """The hand-wired default, clipped to a Mosaic-legal tile for this
+    shape: bk is the largest legal step multiple up to the default that
+    divides K, else all of K."""
     bm, bn, bk = DEFAULT_BLOCK
     bm = min(bm, _pow2_bucket(m))
-    if n % bn or bn > n:
+    if n % bn:
         bn = n
-    align = _bk_align(kind, w_bits)
-    bk = min(bk, k)
-    while bk > align and (k % bk or bk % align):
-        bk //= 2
-    if k % bk or bk % align:
-        bk = k
+    step = _bk_step(kind, w_bits)
+    bk = max((b for b in range(step, max(bk, step) + 1, step) if k % b == 0),
+             default=k)
     return (bm, bn, bk)
 
 
 def candidate_blocks(m: int, n: int, k: int, kind: str, w_bits: int,
                      ) -> list[Block]:
     """MXU-aligned sweep grid; always contains the clipped default."""
+    step = _bk_step(kind, w_bits)
+    bks = sorted({k} | {step * i for i in (1, 2, 4, 8) if step * i < k})
     cands = []
     for bm in (8, 16, 32, 64, 128, 256):
-        for bn in (128, 256, 512):
-            for bk in (128, 256, 512, 1024):
+        for bn in sorted({128, 256, 512, n}):
+            for bk in bks:
                 b = (bm, bn, bk)
                 if _valid_block(m, n, k, kind, w_bits, b):
                     cands.append(b)

@@ -8,6 +8,16 @@ tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the serving and training
+    steps place data with ``NamedSharding``/``shard_map`` and leave the
+    partitioning of everything else to the compiler.  (``make_mesh`` builds
+    ``Explicit`` axes by default, under which ``vmap``, ``dynamic_slice`` and
+    ``scan`` over sharded operands are refused.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,14 +29,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     (DESIGN.md §5)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(n_data: int, n_model: int, n_pod: int = 1):
     """Arbitrary mesh for elastic restarts / smaller slices."""
     if n_pod > 1:
-        return jax.make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

@@ -136,8 +136,18 @@ def _report_trace(batcher, args):
                   f"(host_frac {s['host_frac']:.1%})")
 
 
-def _batcher_loop(model, params, cfg, args, mesh=None):
-    """Continuous batching through the scheduler v2 (SPMD when --mesh)."""
+def synthetic_prompts(cfg, args) -> list[np.ndarray]:
+    """The launcher's ``--requests`` prompts, (1, L) int32 each, drawn from
+    a fixed seed.  Lengths are ragged (``--prompt-len`` minus 0, 1 or 2) to
+    exercise the shape buckets."""
+    rng = np.random.default_rng(1)
+    return [rng.integers(0, cfg.vocab, (1, max(1, args.prompt_len - rid % 3)))
+            .astype(np.int32) for rid in range(args.requests)]
+
+
+def batcher_loop(model, params, cfg, args, mesh=None):
+    """Continuous batching through the scheduler v2 (SPMD when --mesh).
+    Returns every request's generated token stream, in request order."""
     s_max = args.prompt_len + args.gen
     sc = ServingConfig(
         n_slots=args.slots or args.requests, s_max=s_max,
@@ -211,7 +221,6 @@ def _batcher_loop(model, params, cfg, args, mesh=None):
     else:
         print("whole-prompt admission (chunked prefill disabled/unsupported)")
 
-    rng = np.random.default_rng(1)
     slo_cycle = (["premium", "standard", "batch"] if args.slo == "mixed"
                  else [args.slo])
 
@@ -219,12 +228,10 @@ def _batcher_loop(model, params, cfg, args, mesh=None):
         mark = "<eos>" if finished else ""
         print(f"  [rid {req.rid}] tok {tok}{mark}", flush=True)
 
-    for rid in range(args.requests):
-        # ragged prompts exercise the shape buckets
-        plen = max(1, args.prompt_len - (rid % 3))
+    for rid, tokens in enumerate(synthetic_prompts(cfg, args)):
         batcher.submit(Request(
             rid=rid,
-            tokens=rng.integers(0, cfg.vocab, (1, plen)).astype(np.int32),
+            tokens=tokens,
             options=RequestOptions(
                 max_new=args.gen,
                 temperature=args.temperature,
@@ -236,17 +243,18 @@ def _batcher_loop(model, params, cfg, args, mesh=None):
     assert len(done) == args.requests, (len(done), args.requests)
 
     print(batcher.metrics.format())
-    toks = np.array([r.output[:8] for r in sorted(done, key=lambda r: r.rid)])
-    print(f"sample generations (first 8 tokens/request):\n{toks}")
+    streams = [list(r.output) for r in sorted(done, key=lambda r: r.rid)]
+    print("sample generations (first 8 tokens/request):\n"
+          f"{np.array([s[:8] for s in streams])}")
     if args.metrics_json:
         with open(args.metrics_json, "w") as f:
             json.dump(batcher.metrics.summary(), f, indent=1)
         print(f"metrics -> {args.metrics_json}")
     _report_trace(batcher, args)
-    return toks
+    return streams
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="smollm-135m")
     ap.add_argument("--precision", default="2xT")
@@ -350,8 +358,12 @@ def main(argv=None):
                     help="serve SPMD over a (data, model) device mesh, e.g. "
                          "'2,4' (token-LM batcher path only; needs dp*mp "
                          "visible devices)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build(args):
+    """Validate ``args`` and build what serving needs: the model, its
+    serving-form (packed) params, its config and the mesh (or None)."""
     from repro.launch.mesh import parse_mesh
     mesh = parse_mesh(args.mesh)
 
@@ -394,13 +406,20 @@ def main(argv=None):
     print(f"weights: {base_bytes/1e6:.1f} MB bf16-form -> "
           f"{packed_bytes/1e6:.1f} MB {args.precision} serving form "
           f"({base_bytes/packed_bytes:.2f}x smaller)")
+    return model, params, cfg, mesh
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    model, params, cfg, mesh = build(args)
     if cfg.kind != "lm" or cfg.frontend == "embeds":
         if mesh is not None:
             print("--mesh: legacy (embeds/enc-dec) loop is single-device; "
                   "ignoring the mesh")
         return _legacy_loop(model, params, cfg, args)
-    return _batcher_loop(model, params, cfg, args, mesh=mesh)
+    return batcher_loop(model, params, cfg, args, mesh=mesh)
 
 
 if __name__ == "__main__":

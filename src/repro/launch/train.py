@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import Checkpointer
 from repro.configs import ARCH_IDS, get_config
 from repro.data import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build_model, reduce_for_smoke
 from repro.optim import make_optimizer
@@ -53,7 +54,7 @@ def main(argv=None):
     opt = make_optimizer(args.optimizer, lr=args.lr)
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh(n_dev, 1)
 
     def build(n_data, n_model):
         params = model.init(jax.random.PRNGKey(0))

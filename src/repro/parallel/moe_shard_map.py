@@ -25,9 +25,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel._compat import axis_size, shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.layers import _act, _expert_matmul, rmsnorm
@@ -40,7 +39,7 @@ def _local_moe(p, x, cfg: ModelConfig, *, data_axis: str, model_axis: str):
     t = b * s
     e = cfg.n_experts
     k = cfg.top_k
-    n_model = axis_size(model_axis)
+    n_model = jax.lax.axis_size(model_axis)
     e_loc = e // n_model
     j = jax.lax.axis_index(model_axis)
     cap = int(t * k / e * cfg.capacity_factor) or 1     # per-group capacity

@@ -17,9 +17,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel._compat import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.transformer import _apply_period

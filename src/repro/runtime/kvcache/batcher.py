@@ -317,7 +317,7 @@ class PagedBatcher(ContinuousBatcher):
             logits_sh = NamedSharding(mesh, P(None, None, vspec))
             decode_fn, jit_chunk_fn = _decode_fn, chunk_fn
             if shd.pure_dp(cfg, mesh):
-                from repro.parallel._compat import shard_map
+                from jax import shard_map
                 rep_params = jax.tree_util.tree_map(
                     lambda l: P(*(None,) * len(l.shape)), self.params)
                 decode_fn = shard_map(

@@ -493,7 +493,7 @@ class ContinuousBatcher:
         # partitioner's collectives.
         pure = shd.pure_dp(cfg, mesh)
         if pure:
-            from repro.parallel._compat import shard_map
+            from jax import shard_map
             rep_params = jax.tree_util.tree_map(
                 lambda l: P(*(None,) * len(l.shape)), self.params)
             adm_specs = shd.cache_specs(adm_tmpl, cfg, mesh, 1, allow_sp=False)
@@ -516,7 +516,7 @@ class ContinuousBatcher:
         # the compiled step is fully collective-free.
         decode_fn = self._decode_fn
         if self._shard_local_decode(cfg, mesh, baxes):
-            from repro.parallel._compat import shard_map
+            from jax import shard_map
             cache_specs = shd.cache_specs(slot_tmpl, cfg, mesh, self.n_slots,
                                           allow_sp=False)
             decode_fn = shard_map(
@@ -533,7 +533,7 @@ class ContinuousBatcher:
             out_shardings=(dec_logits_sh, pos_sh, self._slot_cache_sh))
         if self.chunk_size:
             if pure:
-                from repro.parallel._compat import shard_map
+                from jax import shard_map
                 chunk_fn = shard_map(
                     lambda p, t, c, pos: model.prefill_chunk(p, t, c, pos),
                     mesh=mesh,

@@ -313,7 +313,7 @@ from repro.analysis.report import StepSpec
 from repro.analysis.rules import audit_step
 from repro.core.precision import get_precision, signed
 from repro.kernels import engine, tuning
-from repro.parallel._compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 

@@ -218,8 +218,11 @@ def test_serving_tune_keys_per_shard_quantized_act(tmp_cache):
     # dp=8 shards the 8-slot decode batch down to 1 local row per device
     assert any(m == 1 for (m, _, _) in plan), plan
 
+    # candidates=[]: each sweep times only the default tile, which is legal
+    # for every shape in the plan (a fixed extra tile cannot be: N=64 and
+    # N=128 admit no common Mosaic-legal bn)
     engine.tune_serving_shapes(cfg, pcfg, n_slots=8, chunk_size=4, mesh=mesh,
-                               candidates=[(8, 64, 16)], iters=1)
+                               candidates=[], iters=1)
     for (m, n, k) in plan:
         assert tuning.lookup(m, n, k, kind=W_TERNARY, a_bits=2, w_bits=2,
                              backend="pallas") is not None, (m, n, k)
@@ -239,12 +242,12 @@ def test_tuning_cache_roundtrip(tmp_cache):
 
     def fake_measure(block):
         calls.append(block)
-        return 1.0 if block != (16, 128, 128) else 0.5
+        return 1.0 if block != (16, 128, 256) else 0.5
 
     entry = tuning.autotune(8, 128, 256, kind=W_TERNARY, a_bits=2, w_bits=2,
                             backend="pallas", measure=fake_measure,
-                            candidates=[(8, 128, 128), (16, 128, 128)])
-    assert tuple(entry["block"]) == (16, 128, 128)
+                            candidates=[(8, 128, 256), (16, 128, 256)])
+    assert tuple(entry["block"]) == (16, 128, 256)
     assert tmp_cache.exists()
     n_swept = len(calls)
     assert n_swept >= 2
@@ -253,11 +256,11 @@ def test_tuning_cache_roundtrip(tmp_cache):
     tuning.reset()
     blk = tuning.get_block_sizes(8, 128, 256, kind=W_TERNARY, a_bits=2,
                                  w_bits=2, backend="pallas")
-    assert blk == (16, 128, 128)
+    assert blk == (16, 128, 256)
     assert tuning.stats()["hits"] == 1 and tuning.stats()["sweeps"] == 0
     tuning.autotune(8, 128, 256, kind=W_TERNARY, a_bits=2, w_bits=2,
                     backend="pallas", measure=fake_measure,
-                    candidates=[(8, 128, 128), (16, 128, 128)])
+                    candidates=[(8, 128, 256), (16, 128, 256)])
     assert len(calls) == n_swept, "second autotune re-swept despite cache"
     assert tuning.stats()["sweeps"] == 0
 
@@ -330,13 +333,13 @@ def test_corrupt_entry_does_not_break_good_entries(tmp_cache):
     good_key = tuning.cache_key(W_TERNARY, 2, 2, "pallas", 8, 128, 256)
     tmp_cache.write_text(json.dumps({
         "version": 1,
-        "entries": {good_key: {"block": [8, 128, 128], "us": 1.0},
+        "entries": {good_key: {"block": [16, 128, 256], "us": 1.0},
                     "broken": {"block": None}},
     }))
     tuning.reset()
     blk = tuning.get_block_sizes(8, 128, 256, kind=W_TERNARY, a_bits=2,
                                  w_bits=2, backend="pallas")
-    assert blk == (8, 128, 128)
+    assert blk == (16, 128, 256)
     assert tuning.stats()["hits"] == 1
 
 
@@ -355,13 +358,13 @@ def test_cache_save_is_atomic(tmp_cache, monkeypatch):
         with pytest.warns(RuntimeWarning):
             tuning.autotune(8, 256, 256, kind=W_TERNARY, a_bits=2, w_bits=2,
                             backend="pallas",
-                            measure=lambda b: 0.5 if b == (8, 256, 128)
+                            measure=lambda b: 0.5 if b == (8, 256, 256)
                             else 1.0,
-                            candidates=[(8, 256, 128)])
+                            candidates=[(8, 256, 256)])
     assert tmp_cache.read_bytes() == before     # old cache intact
     # in-memory state still serves the new entry this process
     assert tuning.get_block_sizes(8, 256, 256, kind=W_TERNARY, a_bits=2,
-                                  w_bits=2, backend="pallas") == (8, 256, 128)
+                                  w_bits=2, backend="pallas") == (8, 256, 256)
 
 
 def test_autotune_matmul_end_to_end(tmp_cache):
@@ -542,3 +545,36 @@ def test_serving_tune_plan_per_device_shapes():
     plan = engine.serving_tune_plan(small, pcfg, n_slots=16, chunk_size=32,
                                     mesh=mesh_tp)
     assert (2, small.d_ff, small.d_model) in plan
+
+
+@pytest.mark.parametrize("k", [576, 1536])
+@pytest.mark.parametrize("kind,bits", [("ternary", 2), ("binary", 1),
+                                       ("int", 2), ("int", 4), ("int", 8)])
+def test_default_tiles_are_mosaic_legal(kind, bits, k):
+    """At smollm's contraction lengths every default and candidate tile
+    keeps each block dim a multiple of (8, 128) or the whole array dim."""
+    cpw = tuning._bk_align(kind, bits)
+    for n in (192, 576, 1536):
+        for m in (1, 8, 200, 256):
+            blocks = [tuning.fallback_block(m, n, k, kind, bits)]
+            blocks += tuning.candidate_blocks(m, n, k, kind, bits)
+            for bm, bn, bk in blocks:
+                assert tuning._valid_block(m, n, k, kind, bits, (bm, bn, bk))
+                assert bm % 8 == 0
+                assert bn == n or (bn % 128 == 0 and n % bn == 0)
+                assert k % bk == 0 and bk % cpw == 0
+                assert bk == k or bk % 128 == 0            # x block lanes
+                bkw = bk // cpw
+                assert bkw == k // cpw or bkw % 128 == 0   # weight lanes
+
+
+def test_illegal_tiles_are_rejected():
+    # the tile the old picker chose at K=576 (bk=64) and a 128-wide bk whose
+    # packed ternary block is 8 words wide
+    assert not tuning._valid_block(8, 576, 576, "ternary", 2, (8, 128, 64))
+    assert not tuning._valid_block(8, 576, 576, "ternary", 2, (8, 128, 128))
+    assert not tuning._valid_block(8, 576, 576, "ternary", 2, (4, 576, 576))
+    assert not tuning._valid_block(8, 576, 576, "ternary", 2, (8, 64, 576))
+    assert tuning._valid_block(8, 576, 576, "ternary", 2, (8, 576, 576))
+    assert tuning._valid_block(8, 4096, 4096, "ternary", 2, (8, 128, 2048))
+    assert tuning.fallback_block(8, 576, 576, "ternary", 2) == (8, 576, 576)

@@ -208,7 +208,7 @@ def test_cache_specs_allow_sp_disables_sequence_sharding():
                          "v": FakeLeaf((2, 1, 64, 4, 32))}}
     # default (B=1, seq 64 divisible by data=4): SP fallback shards the seq
     sp = sh.cache_specs(cache, cfg, mesh, batch=1)
-    assert tuple(sp["layer_0"]["k"])[2] == ("data",)
+    assert tuple(sp["layer_0"]["k"])[2] == "data"   # P canonicalizes ("data",)
     # allow_sp=False: sequence replicated, KV heads still sharded (4 % 4 == 0)
     no_sp = sh.cache_specs(cache, cfg, mesh, batch=1, allow_sp=False)
     assert tuple(no_sp["layer_0"]["k"])[2] is None

@@ -20,7 +20,8 @@ from repro.parallel.moe_shard_map import moe_apply_shard_map
 cfg = reduce_for_smoke(get_config("granite-moe-1b-a400m"))
 cfg = dataclasses.replace(cfg, n_experts=8, top_k=2, capacity_factor=64.0,
                           dtype="float32")   # high cap -> no drops either way
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(2, 4)
 
 key = jax.random.PRNGKey(0)
 p = L.moe_init(key, cfg)

@@ -53,6 +53,48 @@ def test_paged_attention_kernel_matches_ref(b, kv, g, dh, bs, nblk, kv_bits):
                                rtol=2e-5, atol=2e-5)
 
 
+def _bf16_model_attention(q, kp, ks, vp, vs, pt, pos):
+    """What a bf16 model's decode attention computes: K/V dequantized and
+    rounded to bf16 (``layers._kv_dequant``), then f32 softmax attention."""
+    from repro.kernels.paged_attention import gather_pool
+    deq = lambda c, sc: (gather_pool(c, pt).astype(jnp.float32)
+                         * gather_pool(sc, pt)).astype(jnp.bfloat16
+                                                       ).astype(jnp.float32)
+    k, v = deq(kp, ks), deq(vp, vs)                     # (B, S, KV, Dh)
+    dh = q.shape[-1]
+    s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32), k) / dh ** 0.5
+    mask = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bkgs,bskd->bkgd", p, v)
+
+
+@pytest.mark.parametrize("kernel", ["paged", "fused"])
+def test_paged_kernels_round_kv_to_bf16_model_dtype(kernel):
+    """With bf16 queries (a bf16 model), both paged kernels attend over K/V
+    rounded to bf16, and the fused kernel projects bf16-rounded attention,
+    as the model's reference path does; f32 K/V shift every score by up to
+    2^-9 relative, which 2-bit activation codes downstream amplify."""
+    from repro.kernels.decode_fused import fused_decode
+    b, kv, g, dh, bs, nblk, d = 2, 3, 3, 64, 16, 4, 96
+    nb_pool = b * nblk + 1
+    q = jnp.asarray(RNG.normal(size=(b, kv, g, dh))).astype(jnp.bfloat16)
+    kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, 8)
+    pt = _page_table(b, nblk, nb_pool)
+    pos = jnp.asarray([nblk * bs - 1, 21], np.int32)
+    want = _bf16_model_attention(q, kp, ks, vp, vs, pt, pos)
+    if kernel == "paged":
+        got = paged_attention(q, kp, ks, vp, vs, pt, pos, interpret=True)
+    else:
+        wo = jnp.asarray(RNG.normal(size=(kv * g * dh, d))
+                         ).astype(jnp.bfloat16)
+        got = fused_decode(q, kp, ks, vp, vs, pt, pos, jnp.arange(b), wo,
+                           interpret=True)
+        want = jnp.dot(want.astype(jnp.bfloat16).astype(jnp.float32)
+                       .reshape(b, -1), wo.astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_paged_attention_unreferenced_blocks_are_invisible():
     """Poisoning pool blocks no page table references (other requests' data,
     the null block) must not change any output."""

@@ -37,7 +37,8 @@ def seq_blocks(blocks, x):
     return h
 want = seq_blocks(params["blocks"], x)
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 4), ("pod", "data"))
 got = jax.jit(lambda bl, xx: pipeline_blocks(bl, xx, cfg, mesh, axis="pod",
                                              n_micro=4))(params["blocks"], x)
 np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -23,7 +23,8 @@ from repro.parallel.sharding import batch_specs, cache_specs, param_specs
 from repro.launch.steps import make_train_step
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(2, 4)
 sh = lambda specs: jax.tree_util.tree_map(
     lambda s: NamedSharding(mesh, s), specs, is_leaf=lambda x: isinstance(x, P))
 
