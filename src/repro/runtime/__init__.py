@@ -48,8 +48,7 @@ from .policy import (BrownoutController, BrownoutPolicy,  # noqa: F401
 from .profile import StepProfiler  # noqa: F401
 from .serving import (ContinuousBatcher, Request,  # noqa: F401
                       RequestOptions, ServingConfig)
-from .tracing import (MetricsSnapshotter, TraceConfig,  # noqa: F401
-                      Tracer, span_coverage)
+from .tracing import MetricsSnapshotter, TraceConfig, Tracer  # noqa: F401
 
 
 class PreemptionGuard:
@@ -159,9 +158,9 @@ class ElasticTrainer:
         metrics_log = []
         with PreemptionGuard() as guard:
             for step in range(start, n_steps):
-                t0 = time.time()
+                t0 = time.perf_counter()
                 state, metrics = step_fn(state, next(data_iter))
-                wall = time.time() - t0
+                wall = time.perf_counter() - t0
                 if monitor is not None:
                     monitor.record(step, wall)
                 metrics_log.append(metrics)

@@ -205,7 +205,7 @@ class AdaptiveServer:
         self.lanes[0]._validate(req)
         if req.submitted_at == 0.0:
             import time
-            req.submitted_at = time.time()
+            req.submitted_at = time.perf_counter()
             self.metrics.on_submit(req)
         self.queue.append(req)
 
@@ -258,16 +258,11 @@ class AdaptiveServer:
                        level=level, prev_level=prev_level, **signals)
         self._route(level)
         finished: list[Request] = []
-        if tr.enabled:
-            tr.begin("step", "adaptive", track=self.trace_track,
-                     queue_depth=depth, level=level)
-        try:
+        with tr.span("server_step", "adaptive", track=self.trace_track,
+                     queue_depth=depth, level=level):
             for lane in self.lanes:
                 if not lane.idle:
                     finished.extend(lane.step())
-        finally:
-            if tr.enabled:
-                tr.end("step", "adaptive", track=self.trace_track)
         if tr.snapshotter is not None:
             tr.tick_snapshot(self.metrics)
         return finished

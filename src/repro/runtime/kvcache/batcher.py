@@ -538,7 +538,7 @@ class PagedBatcher(ContinuousBatcher):
                 return
             self.queue.popleft()
             readmission = req.started_at != 0.0   # preempted earlier
-            req.started_at = time.time()
+            req.started_at = time.perf_counter()
             self.metrics.on_admit(req, n_prompt_tokens=length,
                                   resumed=readmission)
             start = len(shared) * self.block_size
@@ -588,10 +588,9 @@ class PagedBatcher(ContinuousBatcher):
         self.metrics.prefill_chunks += 1
         tr = self.tracer
         if tr.enabled:
-            tr.begin("prefill_chunk", "scheduler", track=self.trace_track,
-                     rid=adm.req.rid, pos=adm.start + adm.next_pos)
             tr.flow("t", adm.req.rid, track=self.trace_track)
-        try:
+        with tr.span("prefill_chunk", "scheduler", track=self.trace_track,
+                     rid=adm.req.rid, pos=adm.start + adm.next_pos):
             if self.profiler is None:
                 logits, self.pool = self._prefill_chunk(
                     self.params, chunk, self.pool,
@@ -604,9 +603,6 @@ class PagedBatcher(ContinuousBatcher):
                         jnp.asarray(self._adm_row),
                         jnp.int32(adm.start + adm.next_pos))
                     jax.block_until_ready(logits)
-        finally:
-            if tr.enabled:
-                tr.end("prefill_chunk", "scheduler", track=self.trace_track)
         adm.next_pos += c
         if adm.next_pos >= adm.tokens.shape[1]:
             row = logits[0, (adm.length - 1 - adm.start) % c]
@@ -915,22 +911,17 @@ class PagedBatcher(ContinuousBatcher):
         toks = self.tokens
         tr = self.tracer
         n_draft = int(limits.max(initial=0))
-        if tr.enabled:
-            tr.begin("draft", "scheduler", track=self.trace_track,
-                     rounds=n_draft)
-        try:
+        with tr.span("draft", "scheduler", track=self.trace_track,
+                     rounds=n_draft):
             for j in range(n_draft):
                 nxt, self.pool = self._draft_decode(
                     self._draft_params, jnp.asarray(toks), self.pool,
                     jnp.asarray(self._pt), jnp.asarray(base_pos + j))
-                toks = np.asarray(nxt, np.int32).reshape(self.n_slots, 1)
+                with tr.span("decode_fetch", "scheduler",
+                             track=self.trace_track):
+                    toks = np.asarray(nxt, np.int32).reshape(self.n_slots, 1)
                 window[:, j + 1] = toks[:, 0]
-        finally:
-            if tr.enabled:
-                tr.end("draft", "scheduler", track=self.trace_track)
-        if tr.enabled:
-            tr.begin("verify", "scheduler", track=self.trace_track)
-        try:
+        with tr.span("verify", "scheduler", track=self.trace_track):
             if self.profiler is None:
                 logits, greedy, self.pool = self._verify(
                     self.params, jnp.asarray(window), self.pool,
@@ -941,39 +932,39 @@ class PagedBatcher(ContinuousBatcher):
                         self.params, jnp.asarray(window), self.pool,
                         jnp.asarray(self._pt), jnp.asarray(base_pos))
                     jax.block_until_ready((logits, greedy))
-        finally:
-            if tr.enabled:
-                tr.end("verify", "scheduler", track=self.trace_track)
-        greedy = np.asarray(greedy, np.int32)
+        with tr.span("decode_fetch", "scheduler", track=self.trace_track):
+            greedy = np.asarray(greedy, np.int32)
         self.metrics.decode_steps += 1
         drafted = accepted = 0
-        for i, req in enumerate(self.slots):
-            if req is None or self.done[i] or self.stalled[i]:
-                continue
-            lim = int(limits[i])
-            drafted += lim
-            j = 0
-            while True:
-                tok = int(greedy[i, j]) if req.temperature <= 0.0 \
-                    else self._sample(req, logits[i, j])
-                self.metrics.decode_slot_tokens += 1
-                self.pos[i] += 1
-                hit_eos = req.eos_id is not None and tok == req.eos_id
-                full = (len(req.output) + 1 >= req.max_new or hit_eos
-                        or self.pos[i] >= self.s_max - 1)
-                self._emit(req, tok, full)
-                if full:
-                    self._finish(req, i)
+        with tr.span("emit", "scheduler", track=self.trace_track):
+            for i, req in enumerate(self.slots):
+                if req is None or self.done[i] or self.stalled[i]:
+                    continue
+                lim = int(limits[i])
+                drafted += lim
+                j = 0
+                while True:
+                    tok = int(greedy[i, j]) if req.temperature <= 0.0 \
+                        else self._sample(req, logits[i, j])
+                    self.metrics.decode_slot_tokens += 1
+                    self.pos[i] += 1
+                    hit_eos = req.eos_id is not None and tok == req.eos_id
+                    full = (len(req.output) + 1 >= req.max_new or hit_eos
+                            or self.pos[i] >= self.s_max - 1)
+                    self._emit(req, tok, full)
+                    if full:
+                        self._finish(req, i)
+                        accepted += j
+                        break
+                    if j < lim and int(window[i, j + 1]) == tok:
+                        # the draft predicted this very token: its
+                        # successor row in the window already holds the
+                        # exact fp continuation
+                        j += 1
+                        continue
+                    self.tokens[i, 0] = tok
                     accepted += j
                     break
-                if j < lim and int(window[i, j + 1]) == tok:
-                    # the draft predicted this very token: its successor row
-                    # in the window already holds the exact fp continuation
-                    j += 1
-                    continue
-                self.tokens[i, 0] = tok
-                accepted += j
-                break
         self.metrics.on_spec_round(drafted, accepted)
         # the round mutated tokens/pos on the host: any later non-spec
         # decode dispatch must re-stage the device loop buffers
@@ -987,9 +978,8 @@ class PagedBatcher(ContinuousBatcher):
         if not self.spec:
             return super()._step_impl()
         self._tick()
-        self._advance_admission()
-        if not all(self.done):
-            self._pre_decode()
+        self._admit()
+        self._allocate()
         if not all(self.done):
             self._spec_round(self._extend_windows())
         finished, self._just_finished = self._just_finished, []

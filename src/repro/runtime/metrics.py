@@ -11,7 +11,6 @@ only on demand.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 
 PERCENTILES = (50, 90, 99)
@@ -126,8 +125,7 @@ class Metrics:
         self._t1: float | None = None
 
     # ------------------------------------------------------------- recording
-    def _touch(self):
-        now = time.time()
+    def _touch(self, now: float):
         if self._t0 is None:
             self._t0 = now
         self._t1 = now
@@ -141,7 +139,7 @@ class Metrics:
         # windows are reported by summary() so bench history stays
         # comparable)
         if self._t0_submit is None:
-            self._t0_submit = time.time()
+            self._t0_submit = req.submitted_at
 
     def on_admit(self, req, n_prompt_tokens: int | None = None,
                  resumed: bool = False) -> None:
@@ -158,11 +156,12 @@ class Metrics:
         self.requests_active += 1
         self.requests_active_peak = max(self.requests_active_peak,
                                         self.requests_active)
-        self._touch()
+        self._touch(req.started_at)
 
-    def on_token(self, req, first: bool) -> None:
+    def on_token(self, req, first: bool, now: float) -> None:
+        """One emitted token, stamped ``now`` on the batcher's request
+        clock (``time.perf_counter``)."""
         self.tokens_out += 1
-        now = time.time()
         if first:
             self.ttft_ms.append((now - req.submitted_at) * 1e3)
         elif req.last_token_at is not None:
@@ -170,13 +169,13 @@ class Metrics:
             # (monkeypatched clocks in tests) is a real timestamp and its
             # ITL sample must not be dropped
             self.itl_ms.append((now - req.last_token_at) * 1e3)
-        self._touch()
+        self._touch(now)
 
     def on_finish(self, req) -> None:
         self.requests_finished += 1
         self.requests_active = max(self.requests_active - 1, 0)
         self.on_slo_finish(req)
-        self._touch()
+        self._touch(req.finished_at)
 
     # ------------------------------------------------------ paged-KV counters
     def on_prefix_lookup(self, hit_tokens: int, prompt_tokens: int,

@@ -664,7 +664,7 @@ class ContinuousBatcher:
             # counts the request when it enters the CENTRAL queue, and this
             # routing hop into a lane must not re-count it (queue_ms spans
             # the whole wait, not just the post-routing tail)
-            req.submitted_at = time.time()
+            req.submitted_at = time.perf_counter()
             self.metrics.on_submit(req)
         self.queue.append(req)
 
@@ -672,10 +672,10 @@ class ContinuousBatcher:
     def _emit(self, req: Request, tok: int, finished: bool):
         req.output.append(tok)
         first = req.first_token_at == 0.0
-        now = time.time()
+        now = time.perf_counter()
         if first:
             req.first_token_at = now
-        self.metrics.on_token(req, first)
+        self.metrics.on_token(req, first, now)
         req.last_token_at = now
         if first and self.tracer.enabled:
             self.tracer.instant("first_token", "scheduler",
@@ -705,7 +705,7 @@ class ContinuousBatcher:
         return int(jax.random.categorical(key, lg))
 
     def _finish(self, req: Request, slot: int):
-        req.finished_at = time.time()
+        req.finished_at = time.perf_counter()
         self.metrics.on_finish(req)
         if self.tracer.enabled:
             self.tracer.instant("finish", "scheduler", track=self.trace_track,
@@ -752,7 +752,10 @@ class ContinuousBatcher:
         that the decode loop would have applied fires here instead — the
         resumed stream stops exactly where the uninterrupted one would
         have."""
-        tok = self._sample(req, first_logits_row)
+        # the host blocks here on the admission's last logits
+        with self.tracer.span("first_token", "scheduler",
+                              track=self.trace_track, rid=req.rid):
+            tok = self._sample(req, first_logits_row)
         resumed = bool(req.output)
         length = req.tokens.shape[1] + len(req.output)
         finished = (len(req.output) + 1 >= req.max_new
@@ -781,7 +784,7 @@ class ContinuousBatcher:
             if not self.queue or slot is None:
                 return
             req = self.queue.popleft()
-            req.started_at = time.time()
+            req.started_at = time.perf_counter()
             self.metrics.on_admit(req)
             length = req.tokens.shape[1]
             if self.tracer.enabled:
@@ -803,10 +806,9 @@ class ContinuousBatcher:
         self.metrics.prefill_chunks += 1
         tr = self.tracer
         if tr.enabled:
-            tr.begin("prefill_chunk", "scheduler", track=self.trace_track,
-                     rid=adm.req.rid, pos=adm.next_pos)
             tr.flow("t", adm.req.rid, track=self.trace_track)
-        try:
+        with tr.span("prefill_chunk", "scheduler", track=self.trace_track,
+                     rid=adm.req.rid, pos=adm.next_pos):
             if self.profiler is None:
                 logits, self._adm_cache = self._prefill_chunk(
                     self.params, chunk, self._adm_cache,
@@ -817,9 +819,6 @@ class ContinuousBatcher:
                         self.params, chunk, self._adm_cache,
                         jnp.int32(adm.next_pos))
                     jax.block_until_ready(logits)
-        finally:
-            if tr.enabled:
-                tr.end("prefill_chunk", "scheduler", track=self.trace_track)
         adm.next_pos += c
         if adm.next_pos >= adm.tokens.shape[1]:
             # final chunk always contains the last real position L-1
@@ -835,7 +834,7 @@ class ContinuousBatcher:
             if slot is None:
                 return
             req = self.queue.popleft()
-            req.started_at = time.time()
+            req.started_at = time.perf_counter()
             self.metrics.on_admit(req)
             self.metrics.prefill_full += 1
             self.slots[slot] = req
@@ -845,14 +844,10 @@ class ContinuousBatcher:
                            rid=req.rid, slot=slot,
                            prompt_tokens=req.tokens.shape[1])
                 tr.flow("s", req.rid, track=self.trace_track)
-                tr.begin("prefill", "scheduler", track=self.trace_track,
-                         rid=req.rid)
             batch = {"tokens": jnp.asarray(req.tokens, jnp.int32)}
-            try:
+            with tr.span("prefill", "scheduler", track=self.trace_track,
+                         rid=req.rid):
                 logits, one_cache = self._prefill(self.params, batch)
-            finally:
-                if tr.enabled:
-                    tr.end("prefill", "scheduler", track=self.trace_track)
             self._activate(req, slot, one_cache, logits[0, -1])
 
     # ----------------------------------------------------------------- step
@@ -916,13 +911,12 @@ class ContinuousBatcher:
         device in the jitted select step, so there are no per-slot
         round-trips regardless of sampling params (the old non-greedy path
         blocked once per sampled slot per token)."""
-        if self._loop_dirty or live != self._live_list:
-            self._stage_loop_state(live)
-            self._live_list = list(live)
         tr = self.tracer
-        if tr.enabled:
-            tr.begin("decode", "scheduler", track=self.trace_track)
-        try:
+        if self._loop_dirty or live != self._live_list:
+            with tr.span("stage", "scheduler", track=self.trace_track):
+                self._stage_loop_state(live)
+            self._live_list = list(live)
+        with tr.span("decode", "scheduler", track=self.trace_track):
             if self.profiler is None:
                 nxt = self._dispatch_decode()
             else:
@@ -933,10 +927,8 @@ class ContinuousBatcher:
                 with self.profiler.step("decode"):
                     nxt = self._dispatch_decode()
                     jax.block_until_ready(nxt)
-        finally:
-            if tr.enabled:
-                tr.end("decode", "scheduler", track=self.trace_track)
-        return np.asarray(nxt, np.int32)
+        with tr.span("decode_fetch", "scheduler", track=self.trace_track):
+            return np.asarray(nxt, np.int32)
 
     def _pre_decode(self):
         """Hook before the batched decode dispatch.  The paged batcher's
@@ -970,28 +962,34 @@ class ContinuousBatcher:
         :meth:`_step_impl`, which subclasses override for their scheduling
         variants (the paged batcher's speculative rounds)."""
         tr = self.tracer
-        if tr.enabled:
-            tr.begin("step", "scheduler", track=self.trace_track,
-                     queue_depth=len(self.queue))
-            try:
-                finished = self._step_impl()
-            finally:
-                tr.end("step", "scheduler", track=self.trace_track)
-            tr.maybe_tuning_counter()
-        else:
+        with tr.span("step", "scheduler", track=self.trace_track,
+                     queue_depth=len(self.queue)):
             finished = self._step_impl()
+        tr.maybe_tuning_counter()
         if self.tick and tr.snapshotter is not None:
             tr.tick_snapshot(self.metrics)
         return finished
 
+    def _admit(self):
+        """This step's admission work: one prefill chunk, or whole-prompt
+        prefills."""
+        with self.tracer.span("admit", "scheduler", track=self.trace_track):
+            if self.chunk_size:
+                self._advance_admission()
+            else:
+                self._admit_full()
+
+    def _allocate(self):
+        """Block allocation (and preemption) before the decode dispatch."""
+        if not all(self.done):
+            with self.tracer.span("pre_decode", "scheduler",
+                                  track=self.trace_track):
+                self._pre_decode()
+
     def _step_impl(self):
         self._tick()
-        if self.chunk_size:
-            self._advance_admission()
-        else:
-            self._admit_full()
-        if not all(self.done):
-            self._pre_decode()
+        self._admit()
+        self._allocate()
         # stalled slots took no block this step: their write deflected to
         # the null block and their logits would be meaningless — they stay
         # out of the live set and re-feed the same token once a block frees
@@ -999,19 +997,21 @@ class ContinuousBatcher:
         if live:
             nxt = self._decode_call(live)
             self.metrics.decode_steps += 1
-            for i in live:
-                req = self.slots[i]
-                tok = int(nxt[i])
-                self.metrics.decode_slot_tokens += 1
-                self.pos[i] += 1
-                hit_eos = req.eos_id is not None and tok == req.eos_id
-                full = (len(req.output) + 1 >= req.max_new or hit_eos
-                        or self.pos[i] >= self.s_max - 1)
-                self._emit(req, tok, full)
-                if full:
-                    self._finish(req, i)
-                else:
-                    self.tokens[i, 0] = tok
+            with self.tracer.span("emit", "scheduler",
+                                  track=self.trace_track):
+                for i in live:
+                    req = self.slots[i]
+                    tok = int(nxt[i])
+                    self.metrics.decode_slot_tokens += 1
+                    self.pos[i] += 1
+                    hit_eos = req.eos_id is not None and tok == req.eos_id
+                    full = (len(req.output) + 1 >= req.max_new or hit_eos
+                            or self.pos[i] >= self.s_max - 1)
+                    self._emit(req, tok, full)
+                    if full:
+                        self._finish(req, i)
+                    else:
+                        self.tokens[i, 0] = tok
         finished, self._just_finished = self._just_finished, []
         return finished
 

@@ -6,8 +6,10 @@ the compute saturated; :mod:`repro.runtime.metrics` reports *aggregates*
 time" or "what did the brownout controller see the tick it raised".  This
 module records the event stream those questions need:
 
-  * **spans** (begin/end pairs): scheduler step, prefill chunk, decode
-    dispatch, speculative draft/verify rounds;
+  * **spans**, opened only through :meth:`Tracer.span`: the scheduler step
+    and its phases (admission, prefill chunk, first-token wait, block
+    allocation, loop-state staging, decode dispatch, next-token fetch,
+    emission), speculative draft/verify rounds;
   * **instants**: admission, first token, finish, preemption, stall,
     pool-eviction waves, brownout level transitions (with the
     ``controller_signals()`` snapshot that caused them), engine kernel
@@ -16,13 +18,20 @@ module records the event stream those questions need:
   * **flow events** linking one request's admission → chunks → first token
     → finish (→ re-admission after preemption) across slots and lanes.
 
+Every span also enters ``jax.profiler.TraceAnnotation("repro.<name>")``,
+whether or not the ring records: under a running ``jax.profiler`` session
+the scheduler's phases land on the profiler's host plane, on the same
+clock as the device timeline; with no session running the annotation costs
+well under a microsecond.  The profiler session is the only switch for
+these spans.
+
 Events land in a bounded ring buffer (``collections.deque(maxlen=...)``,
 drop-oldest; the drop count is exposed and exported).  The hot-path cost is
 one dict construction + deque append per event when enabled and a single
-attribute check when disabled — tracer calls never allocate on the disabled
-path, and they NEVER appear inside jit-compiled step functions (the
-``tracing-in-jit`` astlint rule enforces this: a tracer call traced into a
-jaxpr would either crash lowering or silently record once at compile time).
+attribute check when disabled.  Tracer calls NEVER appear inside
+jit-compiled step functions (the ``tracing-in-jit`` astlint rule enforces
+this: a tracer call traced into a jaxpr would either crash lowering or
+silently record once at compile time).
 
 Exporters:
   * :meth:`Tracer.to_perfetto` — chrome://tracing / Perfetto JSON.  Ring
@@ -36,16 +45,20 @@ Exporters:
     (plus numeric-leaf deltas vs the previous snapshot) to JSONL, for
     load-over-time plots; ``launch/serve.py --metrics-interval`` rides it.
 
-Timestamps are ``time.perf_counter`` microseconds relative to the tracer's
-construction (the chrome-trace unit); snapshot lines also carry wall time.
+Ring timestamps are ``time.perf_counter`` microseconds relative to the
+tracer's construction (the chrome-trace unit); snapshot lines also carry
+wall time.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
 from collections import deque
 from typing import Any
+
+import jax
 
 # well-known track (chrome "thread") names; batchers may add their own
 # (the adaptive server names one track per lane)
@@ -81,7 +94,8 @@ class Tracer:
 
     Every recording method is a no-op behind one ``self.enabled`` check —
     call sites guard with ``if tr.enabled:`` where they would otherwise
-    build kwargs, so the disabled path allocates nothing."""
+    build kwargs.  :meth:`span` is the one exception: it always enters its
+    profiler annotation, and records into the ring only when enabled."""
 
     def __init__(self, capacity: int = 65536, *, enabled: bool = True):
         self.enabled = bool(enabled)
@@ -149,6 +163,22 @@ class Tracer:
         self._append({"ph": "E", "name": name, "cat": cat,
                       "ts": self._now_us(), "pid": _PID,
                       "tid": self.track(track), "args": args})
+
+    @contextlib.contextmanager
+    def span(self, name: str, cat: str, track: str = TRACK_SCHEDULER,
+             **args):
+        """Open a span: ``repro.<name>`` on the profiler's host plane always,
+        and the ring's ``B``/``E`` pair under ``name`` when enabled (closed
+        on an exception too).  Host code only — never inside jitted code."""
+        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+            if not self.enabled:
+                yield
+                return
+            self.begin(name, cat, track, **args)
+            try:
+                yield
+            finally:
+                self.end(name, cat, track)
 
     def instant(self, name: str, cat: str, track: str = TRACK_SCHEDULER,
                 **args) -> None:
@@ -392,39 +422,3 @@ def _numeric_delta(prev: Any, cur: Any) -> Any:
         return cur - base
     return None
 
-
-def span_coverage(trace: dict, name: str = "step") -> float:
-    """Fraction of the trace's wall window covered by the union of closed
-    ``name`` spans (any track) — the acceptance metric "per-step spans
-    account for ≥95% of the serving window"."""
-    evs = [e for e in trace["traceEvents"] if e["ph"] != "M"]
-    if not evs:
-        return 0.0
-    t_lo = min(e["ts"] for e in evs)
-    t_hi = max(e["ts"] + e.get("dur", 0.0) for e in evs)
-    window = t_hi - t_lo
-    if window <= 0.0:
-        return 1.0
-    intervals: list[tuple[float, float]] = []
-    open_: dict[int, list[float]] = {}
-    for e in evs:
-        if e.get("name") != name:
-            continue
-        if e["ph"] == "B":
-            open_.setdefault(e["tid"], []).append(e["ts"])
-        elif e["ph"] == "E":
-            st = open_.get(e["tid"])
-            if st:
-                intervals.append((st.pop(), e["ts"]))
-        elif e["ph"] == "X":
-            intervals.append((e["ts"], e["ts"] + e.get("dur", 0.0)))
-    covered = 0.0
-    end = None
-    for lo, hi in sorted(intervals):
-        if end is None or lo > end:
-            covered += hi - lo
-            end = hi
-        elif hi > end:
-            covered += hi - end
-            end = hi
-    return covered / window

@@ -218,6 +218,27 @@ def test_lint_tracing_in_jit_call():
                                rules=("tracing-in-jit",)) == []
 
 
+def test_lint_tracing_in_jit_span():
+    """A span opened inside a jitted function fires like any tracer call;
+    the same span around the call is the supported pattern."""
+    src = ("import jax\n"
+           "def _decode_fn(p, t):\n"
+           "    with self.tracer.span('decode', 'scheduler'):\n"
+           "        return t\n"
+           "decode = jax.jit(_decode_fn)\n")
+    f = _fire(src, "src/repro/runtime/foo.py", "tracing-in-jit")
+    assert "_decode_fn" in f.message
+    ok = ("import jax\n"
+          "def _decode_fn(p, t):\n"
+          "    return t\n"
+          "decode = jax.jit(_decode_fn)\n"
+          "def step(self):\n"
+          "    with self.tracer.span('decode', 'scheduler'):\n"
+          "        return decode(None, None)\n")
+    assert astlint.lint_source(ok, "src/repro/runtime/foo.py",
+                               rules=("tracing-in-jit",)) == []
+
+
 def test_lint_tracing_in_jit_lambda():
     src = "f = jax.jit(lambda p, b: tracer.instant('x', 'y') or b)\n"
     f = _fire(src, "src/repro/launch/foo.py", "tracing-in-jit")

@@ -19,7 +19,7 @@ def _req(submitted_at=0.0, started_at=0.0, prompt_len=4,
          last_token_at=None):
     return types.SimpleNamespace(
         submitted_at=submitted_at, started_at=started_at,
-        last_token_at=last_token_at,
+        last_token_at=last_token_at, finished_at=0.0,
         tokens=np.zeros((1, prompt_len), np.int32))
 
 
@@ -56,15 +56,17 @@ def test_pcts_nearest_rank_regression_cases():
 # ---------------------------------------------------------------------------
 def test_throughput_window_starts_at_first_admission():
     m = Metrics(n_slots=2)
-    r = _req()
+    r = _req(submitted_at=time.perf_counter())
     m.on_submit(r)
     time.sleep(0.08)                       # pure queue-idle: no compute yet
-    r.started_at = time.time()
+    r.started_at = time.perf_counter()
     m.on_admit(r)
     for _ in range(4):
-        m.on_token(r, first=(_ == 0))
-        r.last_token_at = time.time()
+        now = time.perf_counter()
+        m.on_token(r, first=(_ == 0), now=now)
+        r.last_token_at = now
         time.sleep(0.002)
+    r.finished_at = time.perf_counter()
     m.on_finish(r)
 
     s = m.summary()
@@ -76,7 +78,7 @@ def test_throughput_window_starts_at_first_admission():
     # must not extend either window's END
     wall_before = m.wall_s
     time.sleep(0.03)
-    m.on_submit(_req())
+    m.on_submit(_req(submitted_at=time.perf_counter()))
     assert m.wall_s == wall_before
     assert th["since_submit"]["wall_s"] == m.wall_since_submit_s
     assert th["tok_per_s"] > th["since_submit"]["tok_per_s"]
@@ -96,9 +98,9 @@ def test_itl_records_sample_when_last_token_at_is_epoch_zero():
     r = _req()
     m.on_submit(r)
     m.on_admit(r)
-    m.on_token(r, first=True)
+    m.on_token(r, first=True, now=time.perf_counter())
     r.last_token_at = 0.0                  # epoch-zero: a REAL timestamp
-    m.on_token(r, first=False)
+    m.on_token(r, first=False, now=time.perf_counter())
     assert len(m.itl_ms) == 1 and m.itl_ms[0] > 0.0
 
 
@@ -107,7 +109,7 @@ def test_itl_skips_sample_when_no_previous_token():
     r = _req()                             # last_token_at=None: no history
     m.on_submit(r)
     m.on_admit(r)
-    m.on_token(r, first=False)             # defensive path
+    m.on_token(r, first=False, now=time.perf_counter())  # defensive path
     assert m.itl_ms == []
 
 
@@ -214,7 +216,7 @@ def test_preemption_absent_from_format_when_zero():
     r = _req()
     m.on_submit(r)
     m.on_admit(r)
-    m.on_token(r, first=True)
+    m.on_token(r, first=True, now=time.perf_counter())
     m.on_finish(r)
     assert "preemptions" not in m.format()      # dense batcher: no noise
     assert m.summary()["scheduler"]["preemptions"] == 0
@@ -223,11 +225,12 @@ def test_preemption_absent_from_format_when_zero():
 def test_throughput_windows_coincide_under_immediate_admission():
     """No queueing: both windows agree (continuity for old bench numbers)."""
     m = Metrics(n_slots=1)
-    r = _req()
+    r = _req(submitted_at=time.perf_counter())
     m.on_submit(r)
-    r.started_at = time.time()
+    r.started_at = time.perf_counter()
     m.on_admit(r)
-    m.on_token(r, first=True)
+    m.on_token(r, first=True, now=time.perf_counter())
+    r.finished_at = time.perf_counter()
     m.on_finish(r)
     s = m.summary()["throughput"]
     assert abs(s["wall_s"] - s["since_submit"]["wall_s"]) < 0.05

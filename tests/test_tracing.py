@@ -1,7 +1,8 @@
 """Serving flight-recorder tests: ring semantics, Perfetto export schema,
-snapshot/delta stream, profiler sanity, crash dumps — and the load-bearing
-invariant that tracing OBSERVES the scheduler without perturbing it (greedy
-token streams bit-identical with the recorder on vs off).
+snapshot/delta stream, profiler sanity, crash dumps, the ``repro.*`` spans
+on a ``jax.profiler`` capture — and the load-bearing invariant that
+tracing OBSERVES the scheduler without perturbing it (greedy token streams
+bit-identical with the recorder on vs off).
 """
 import dataclasses
 import json
@@ -19,8 +20,7 @@ from repro.runtime.profile import StepProfiler
 from repro.runtime.serving import (ContinuousBatcher, Request,
                                    RequestOptions, ServingConfig)
 from repro.runtime.tracing import (NULL_TRACER, MetricsSnapshotter,
-                                   TraceConfig, Tracer, _numeric_delta,
-                                   span_coverage)
+                                   TraceConfig, Tracer, _numeric_delta)
 
 _STATE = {}
 
@@ -171,20 +171,42 @@ def test_dump_jsonl_header_and_tail(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# span coverage
+# spans
 # ---------------------------------------------------------------------------
-def test_span_coverage_union():
+def _untimed(events):
+    return [{k: v for k, v in e.items() if k != "ts"} for e in events]
+
+
+def test_null_tracer_span_records_nothing():
+    with NULL_TRACER.span("step", "scheduler", queue_depth=3):
+        with NULL_TRACER.span("decode", "scheduler"):
+            pass
+    assert list(NULL_TRACER.events) == [] and NULL_TRACER.dropped == 0
+
+
+def test_span_leaves_the_begin_end_pair():
+    old, new = Tracer(capacity=64), Tracer(capacity=64)
+    old.begin("prefill_chunk", "scheduler", track="lane", rid=4, pos=8)
+    old.end("prefill_chunk", "scheduler", track="lane")
+    with new.span("prefill_chunk", "scheduler", track="lane", rid=4, pos=8):
+        pass
+    assert _untimed(new.events) == _untimed(old.events)
+    assert [e["ph"] for e in new.events] == ["B", "E"]
+    assert new.events[0]["args"] == {"rid": 4, "pos": 8}
+
+
+def test_span_closes_on_an_exception():
+    class Boom(RuntimeError):
+        pass
+
     tr = Tracer(capacity=64)
-    tr.instant("lo", "t")                  # window anchors
-    tr.begin("step", "t")
-    tr.end("step", "t")
-    tr.begin("step", "t")
-    tr.end("step", "t")
-    doc = tr.to_perfetto()
-    cov = span_coverage(doc)
-    assert 0.0 < cov <= 1.0
-    assert span_coverage(doc, name="absent") == 0.0
-    assert span_coverage({"traceEvents": []}) == 0.0
+    with pytest.raises(Boom):
+        with tr.span("step", "scheduler"):
+            with tr.span("decode", "scheduler"):
+                raise Boom
+    assert [(e["ph"], e["name"]) for e in tr.events] == [
+        ("B", "step"), ("B", "decode"), ("E", "decode"), ("E", "step")]
+    _validate_perfetto(tr.to_perfetto())
 
 
 # ---------------------------------------------------------------------------
@@ -240,9 +262,10 @@ def test_profiler_summary_and_trace_spans():
 @pytest.mark.slow
 def test_traced_run_schema_coverage_and_identical_streams(tmp_path):
     """One PagedBatcher workload run twice — recorder on vs off.  The traced
-    run must export a schema-valid Perfetto doc whose step spans cover the
-    serving window, and every greedy stream must be bit-identical to the
-    untraced run (observability must not touch scheduling or numerics)."""
+    run must export a schema-valid Perfetto doc in which every step span
+    the scheduler opened was closed, and every greedy stream must be
+    bit-identical to the untraced run (observability must not touch
+    scheduling or numerics)."""
     cfg, model, params = _setup()
     sc = ServingConfig(n_slots=3, s_max=24, chunk_size=4, kv_bits=16,
                        block_size=4)
@@ -263,13 +286,99 @@ def test_traced_run_schema_coverage_and_identical_streams(tmp_path):
     assert traced_out == plain_out         # bit-identical streams
     doc = traced_b.tracer.to_perfetto(str(tmp_path / "t.json"))
     _validate_perfetto(doc)
-    assert span_coverage(doc) >= 0.95
+    steps = [e for e in doc["traceEvents"] if e.get("name") == "step"]
+    n_steps = traced_b.metrics.scheduler_steps
+    assert n_steps > 0
+    assert [e["ph"] for e in steps] == ["B", "E"] * n_steps
+    assert not any(e.get("args", {}).get("synthetic_close") for e in steps)
     names = {e.get("name") for e in doc["traceEvents"]}
     for expected in ("step", "decode", "prefill_chunk", "admit", "finish",
                      "first_token", "req", "kv_blocks"):
         assert expected in names, expected
     # the file written is valid JSON and identical to the returned doc
     assert json.loads((tmp_path / "t.json").read_text()) == doc
+
+
+def _profiled_spans(trace_dir):
+    """The ``repro.*`` host spans of a ``jax.profiler`` capture as
+    ``(start, end, name, children)``, nested by containment per thread."""
+    [pb] = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(pb))
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted((e.start_ns, -(e.start_ns + e.duration_ns), e.name)
+                         for e in line.events
+                         if e.name.startswith("repro."))
+            stack = []
+            for a, nb, name in evs:
+                span = (a, -nb, name[len("repro."):], [])
+                while stack and span[1] > stack[-1][1]:
+                    stack.pop()
+                if stack:
+                    stack[-1][3].append(span)
+                stack.append(span)
+                out.append(span)
+    return out
+
+
+def _within(span, name):
+    found = []
+    for c in span[3]:
+        if c[2] == name:
+            found.append(c)
+        found += _within(c, name)
+    return found
+
+
+def test_profiler_capture_nests_the_scheduler_phases(tmp_path):
+    """With no trace config (the shared disabled tracer) and a profiler
+    session running, every scheduler step lands on the profiler's host
+    plane with its phases nested inside it, and the served tokens are
+    those of the same run without the session."""
+    cfg, model, params = _setup()
+    sc = ServingConfig(n_slots=3, s_max=24, chunk_size=4, kv_bits=16,
+                       block_size=4)
+
+    def run():
+        b = PagedBatcher(model, params, sc)
+        assert b.tracer is NULL_TRACER
+        for r in _requests(cfg):
+            b.submit(r)
+        done = b.run()
+        return b, {r.rid: list(r.output) for r in done}
+
+    _, plain_out = run()
+    with jax.profiler.trace(str(tmp_path)):
+        b, traced_out = run()
+    assert traced_out == plain_out
+    assert list(NULL_TRACER.events) == []
+
+    spans = _profiled_spans(tmp_path)
+    steps = [s for s in spans if s[2] == "step"]
+    assert len(steps) == b.metrics.scheduler_steps
+    decode_steps = [s for s in steps if _within(s, "decode")]
+    assert len(decode_steps) == b.metrics.decode_steps > 0
+    for s in decode_steps:
+        assert len(_within(s, "decode")) == 1
+        assert len(_within(s, "decode_fetch")) == 1
+        assert len(_within(s, "emit")) == 1
+    assert all(not _within(s, "decode_fetch") for s in steps
+               if s not in decode_steps)
+    # every prompt finishes in a step of its own, which holds its first
+    # token's wait inside the step's admission
+    first = [s for s in steps if _within(s, "first_token")]
+    assert len(first) == len(traced_out) == 4
+    for s in first:
+        [admit] = [c for c in s[3] if c[2] == "admit"]
+        assert len(_within(admit, "first_token")) == 1
+    # every prefill chunk is dispatched inside the step's admission
+    chunks = [c for s in spans if s[2] == "admit"
+              for c in _within(s, "prefill_chunk")]
+    assert len(chunks) == b.metrics.prefill_chunks
+    assert len([s for s in spans if s[2] == "prefill_chunk"]) == len(chunks)
 
 
 @pytest.mark.slow
