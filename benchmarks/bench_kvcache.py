@@ -224,6 +224,7 @@ def dead_block_guard_bench():
     same long table — the per-step cost now tracks *live* blocks, not the
     padded table length.
     """
+    from repro.kernels import kv_pool
     from repro.kernels.paged_attention import paged_attention
     rng = np.random.default_rng(3)
     b, kv, g, dh, bs, nblk, live = 2, 2, 4, 64, 16, 48, 3
@@ -238,8 +239,10 @@ def dead_block_guard_bench():
     pos_short = jnp.asarray([live * bs - 1, live * bs - 5], np.int32)
     pos_full = jnp.asarray([nblk * bs - 1, nblk * bs - 1], np.int32)
 
+    pool = kv_pool.from_heads(kp[None], ks[None], vp[None], vs[None])
     run = lambda table, pos: paged_attention(
-        q, kp, ks, vp, vs, table, pos, kv_bits=8, interpret=True)
+        q, pool[kv_pool.CODES], pool[kv_pool.SCALES], table, pos, 0,
+        kv_bits=8, interpret=True)
     out_long = run(pt, pos_short)
     out_live = run(pt[:, :live], pos_short)
     np.testing.assert_array_equal(np.asarray(out_long), np.asarray(out_live))

@@ -200,7 +200,7 @@ def _paired_kernels(gaps):
             paired = functools.partial(_paired, key, fn, ref, gaps)
             if key[0] == engine.ATTN_FUSED:
                 def paired(*a, _fn=fn, _paired_fn=paired, **kw):
-                    wo_p = a[5][3]
+                    wo_p = a[3][3]
                     return (_fn if "wt_packed" in wo_p else _paired_fn)(*a, **kw)
             reg[key] = paired
     try:

@@ -18,8 +18,9 @@ covers both, and the grid's slot dimension runs over **live slots only**:
     softmax scratch carried across iterations — sequence-parallel partial
     accumulation (the split-K of flash decode), with the per-block
     ``pl.when(j * bs <= pos)`` live guard so blocks wholly beyond ``pos``
-    skip dequant and both dots.  Each step moves one pool block for all KV
-    heads, laid out as in :mod:`repro.kernels.paged_attention`.
+    skip dequant and both dots.  Each step moves one pool block of one
+    layer for all KV heads, in place from the stacked pool, as in
+    :mod:`repro.kernels.paged_attention`.
   * the output projection is folded into the final block step: attention is
     linear in the value heads, so each query head ``h`` contributes
     ``attn_h @ wo[h·Dh : (h+1)·Dh]`` to the slot's (1, D) output.  ``wo``'s
@@ -31,10 +32,12 @@ VMEM) — the quantized-``wo`` epilogue (per-row activation requantization)
 stays in the engine's composition fallback so its numerics never fork from
 ``qmatmul``.
 
-Layout (per device, post-sharding):
+Layout (per device, post-sharding; the pool as :mod:`repro.kernels.kv_pool`
+stores it):
   q          : (B, KV, G, Dh)    padded batch of current-token queries
-  k/v pool   : (NB, bs, KV, Dh') int8 codes (kv_bits<=8) or float (16)
-  k/v scale  : (NB, bs, KV, 1)   f32 per-(position, head) (None for 16)
+  kv_pool    : (L, NB, bs/P, P*2*KV*Dh') int8 codes (kv_bits<=8) or float
+  kv_scale   : (L, NBR, R*2*KV*bs)   f32 per-(position, head) (None for 16)
+  layer      : ()                int32 (scalar prefetch)
   page_table : (B, n_blocks)     int32 (scalar prefetch)
   pos        : (B,)              int32 (scalar prefetch)
   slot_map   : (L,)              int32 live slot ids (scalar prefetch)
@@ -52,13 +55,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (attend_block, heads_view,
-                              init_softmax_scratch)
+from . import kv_pool as kv_pool_lib
+from .paged_attention import (attend_block, init_softmax_scratch,
+                              layer_operand, pool_block_specs)
 
 
-def fused_decode_kernel(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, ks_ref,
-                        vp_ref, vs_ref, wo_ref, out_ref, m_ref, l_ref,
-                        acc_ref, *, bs: int, n_blocks: int, kv: int, dh: int,
+def fused_decode_kernel(lyr_ref, sm_ref, pt_ref, pos_ref, q_ref, kv_ref,
+                        sc_ref, wo_ref, out_ref, m_ref, l_ref, acc_ref, *,
+                        bs: int, n_blocks: int, kv: int, dh: int,
                         kv_bits: int):
     li = pl.program_id(0)
     j = pl.program_id(1)
@@ -72,9 +76,9 @@ def fused_decode_kernel(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, ks_ref,
     # the identity, so skipping it is bit-identical (see paged_attention)
     @pl.when(j * bs <= pos_ref[slot])
     def _live_block():
-        attend_block(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, m_ref, l_ref,
-                     acc_ref, j=j, pos=pos_ref[slot], bs=bs, kv=kv, dh=dh,
-                     kv_bits=kv_bits)
+        attend_block(q_ref, kv_ref, sc_ref, m_ref, l_ref, acc_ref, j=j,
+                     pos=pos_ref[slot], phys=pt_ref[slot, j], bs=bs, kv=kv,
+                     dh=dh, kv_bits=kv_bits)
 
     # epilogue: project every query head's attention output, rounded to
     # the model dtype as the unfused layer rounds it, through its wo row
@@ -94,23 +98,25 @@ def fused_decode_kernel(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, ks_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("kv_bits", "interpret"))
-def fused_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
-                 slot_map, wo, *, kv_bits: int = 8, interpret: bool = False):
+def fused_decode(q, kv_pool, kv_scale, page_table, pos, slot_map, wo,
+                 layer, *, kv_bits: int = 8, interpret: bool = False):
     """One fused decode step: live-slot paged attention + output projection.
 
     ``slot_map`` (L,) selects the live rows of ``q``/``page_table``/``pos``;
     the result is compact (L, D) f32 — callers scatter it back to the padded
     batch (``jnp.zeros((B, D)).at[slot_map].set(out)``).  ``wo`` is the dense
-    float (KV*G*Dh, D) projection weight.
+    float (KV*G*Dh, D) projection weight; ``layer`` picks the layer of the
+    stacked pool.
     """
     b, kv, g, dh = q.shape
-    bs = k_pool.shape[1]
+    has_scale = kv_scale is not None
+    assert has_scale == (kv_bits < 16), (kv_bits, has_scale)
+    assert wo.shape[0] == kv * g * dh, (wo.shape, (kv, g, dh))
+    bs = kv_pool_lib.block_positions(kv_pool, kv,
+                                     kv_pool_lib.store_dh(dh, kv_bits))
     n_blocks = page_table.shape[1]
     n_live = slot_map.shape[0]
     d_out = wo.shape[1]
-    has_scale = k_scale is not None
-    assert has_scale == (kv_bits < 16), (kv_bits, has_scale)
-    assert wo.shape[0] == kv * g * dh, (wo.shape, (kv, g, dh))
     pt = page_table.astype(jnp.int32)
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     sm = slot_map.astype(jnp.int32)
@@ -118,36 +124,31 @@ def fused_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
     kern = functools.partial(fused_decode_kernel, bs=bs, n_blocks=n_blocks,
                              kv=kv, dh=dh, kv_bits=kv_bits)
     if not has_scale:
-        # kv_bits=16: no scale operands; close the kernel over None refs
-        def kern_ns(sm_ref, pt_ref, pos_ref, q_ref, kp_ref, vp_ref, wo_ref,
+        # kv_bits=16: no scale operand; close the kernel over a None ref
+        def kern_ns(lyr_ref, sm_ref, pt_ref, pos_ref, q_ref, kv_ref, wo_ref,
                     out_ref, m_ref, l_ref, acc_ref):
             return fused_decode_kernel(
-                sm_ref, pt_ref, pos_ref, q_ref, kp_ref, None, vp_ref, None,
+                lyr_ref, sm_ref, pt_ref, pos_ref, q_ref, kv_ref, None,
                 wo_ref, out_ref, m_ref, l_ref, acc_ref, bs=bs,
                 n_blocks=n_blocks, kv=kv, dh=dh, kv_bits=kv_bits)
         kern = kern_ns
 
-    block_map = lambda li, j, sm, pt, pos: (pt[sm[li], j], 0, 0)
-    pool_spec = pl.BlockSpec((1, bs, kv * k_pool.shape[-1]), block_map)
-    scale_spec = pl.BlockSpec((1, bs, kv), block_map)
     q_spec = pl.BlockSpec(
-        (1, kv, g, dh), lambda li, j, sm, pt, pos: (sm[li], 0, 0, 0))
+        (1, kv, g, dh), lambda li, j, lyr, sm, pt, pos: (sm[li], 0, 0, 0))
     wo_spec = pl.BlockSpec(
-        (kv * g * dh, d_out), lambda li, j, sm, pt, pos: (0, 0))
-    if has_scale:
-        in_specs = [q_spec, pool_spec, scale_spec, pool_spec, scale_spec,
-                    wo_spec]
-        pooled = (k_pool, k_scale, v_pool, v_scale)
-    else:
-        in_specs = [q_spec, pool_spec, pool_spec, wo_spec]
-        pooled = (k_pool, v_pool)
+        (kv * g * dh, d_out), lambda li, j, lyr, sm, pt, pos: (0, 0))
+    in_specs = [q_spec] + pool_block_specs(
+        kv_pool, kv_scale,
+        lambda li, j, lyr, sm, pt, pos: (lyr[0], pt[sm[li], j]),
+        kv=kv, bs=bs) + [wo_spec]
+    operands = (q, kv_pool) + ((kv_scale,) if has_scale else ()) + (wo,)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(n_live, n_blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, d_out),
-                               lambda li, j, sm, pt, pos: (li, 0, 0)),
+                               lambda li, j, lyr, sm, pt, pos: (li, 0, 0)),
         scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),
                         pltpu.VMEM((kv, g, 1), jnp.float32),
                         pltpu.VMEM((kv, g, dh), jnp.float32)],
@@ -162,20 +163,19 @@ def fused_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(sm, pt, pos_b, q, *(heads_view(x) for x in pooled), wo)
+    )(layer_operand(layer), sm, pt, pos_b, *operands)
     return out[:, 0]
 
 
-def fused_decode_ref(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
-                     slot_map, wo, *, kv_bits: int = 8,
+def fused_decode_ref(q, kv_pool, kv_scale, page_table, pos, slot_map, wo,
+                     layer, *, kv_bits: int = 8,
                      out_dtype=jnp.float32):
     """jnp oracle: gather the live rows, run the paged-attention reference,
     project through ``wo``, scatter back compactly (L, D)."""
     from .paged_attention import paged_attention_ref
     ql = q[slot_map]
-    attn = paged_attention_ref(q[slot_map], k_pool, k_scale, v_pool, v_scale,
-                               page_table[slot_map],
-                               jnp.asarray(pos)[slot_map], kv_bits=kv_bits,
-                               out_dtype=jnp.float32)
+    attn = paged_attention_ref(ql, kv_pool, kv_scale, page_table[slot_map],
+                               jnp.asarray(pos)[slot_map], layer,
+                               kv_bits=kv_bits, out_dtype=jnp.float32)
     flat = attn.reshape(ql.shape[0], -1)
     return jnp.dot(flat, wo.astype(jnp.float32)).astype(out_dtype)

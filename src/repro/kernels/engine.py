@@ -603,21 +603,21 @@ def _decode_attn_pallas(q, k, ks, v, vs, pos, *, kv_bits, dtype, block,
 
 
 @register_attention(ATTN_PAGED, (16, 8, 4), BACKEND_XLA)
-def _paged_attn_xla(q, k, ks, v, vs, pt_pos, *, kv_bits, dtype, block,
-                    interpret):
+def _paged_attn_xla(q, kv, kvs, extras, *, kv_bits, dtype, block, interpret):
     from .paged_attention import paged_attention_ref
-    page_table, pos = pt_pos
-    return paged_attention_ref(q, k, ks, v, vs, page_table, pos,
+    page_table, pos, layer = extras
+    return paged_attention_ref(q, kv, kvs, page_table, pos, layer,
                                kv_bits=kv_bits, out_dtype=dtype)
 
 
 @register_attention(ATTN_PAGED, (16, 8, 4), BACKEND_PALLAS)
-def _paged_attn_pallas(q, k, ks, v, vs, pt_pos, *, kv_bits, dtype, block,
+def _paged_attn_pallas(q, kv, kvs, extras, *, kv_bits, dtype, block,
                        interpret):
     from .paged_attention import paged_attention
-    page_table, pos = pt_pos
-    return paged_attention(q, k, ks, v, vs, page_table, pos,
-                           kv_bits=kv_bits, interpret=interpret).astype(dtype)
+    page_table, pos, layer = extras
+    return paged_attention(q, kv, kvs, page_table, pos, layer,
+                           kv_bits=kv_bits,
+                           interpret=interpret).astype(dtype)
 
 
 def decode_attention(q, k_codes, k_scale, v_codes, v_scale, pos, *,
@@ -648,12 +648,13 @@ def decode_attention(q, k_codes, k_scale, v_codes, v_scale, pos, *,
               dtype=dtype, block=block, interpret=interpret)
 
 
-def paged_attention(q, k_pool, k_scale, v_pool, v_scale, page_table, pos, *,
+def paged_attention(q, kv_pool, kv_scale, page_table, pos, *, layer,
                     kv_bits: int = 8, dtype=jnp.float32,
                     backend: str | None = None,
                     interpret: bool | None = None):
     """One-step paged decode attention (block pool + page table) via the
-    registry.  Pool leaves (NB, bs, KV, Dh'); page_table (B, n_blocks)."""
+    registry.  Pool leaves as :mod:`repro.kernels.kv_pool` stores them,
+    stacked over layers (``layer`` picks one); page_table (B, n_blocks)."""
     backend = backend or default_backend()
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -662,7 +663,7 @@ def paged_attention(q, k_pool, k_scale, v_pool, v_scale, page_table, pos, *,
                      requested_backend=backend, impl_backend=matched[2],
                      a_bits=kv_bits, w_bits=8, m_rows=int(q.shape[0]),
                      a_scale_shape=None, block=None)
-    return fn(q, k_pool, k_scale, v_pool, v_scale, (page_table, pos),
+    return fn(q, kv_pool, kv_scale, (page_table, pos, layer),
               kv_bits=kv_bits, dtype=dtype, block=None, interpret=interpret)
 
 
@@ -698,6 +699,28 @@ def autotune_decode_attention(*, b: int, s: int, kv: int, g: int, dh: int,
                            measure=measure, candidates=cands, force=force)
 
 
+def _random_pool(rng, n_pool: int, bs: int, kv: int, dh: int,
+                 kv_bits: int):
+    """A one-layer stacked pool of random codes and scales (floats at 16),
+    as :mod:`repro.kernels.kv_pool` stores it: ``(codes, scales or
+    None)``."""
+    from . import kv_pool
+    if kv_bits == 16:
+        mk = lambda: rng.normal(size=(1, n_pool, bs, kv, dh)
+                                ).astype(np.float32)
+        pool = kv_pool.from_heads(jnp.asarray(mk()), None, jnp.asarray(mk()),
+                                  None)
+        return pool[kv_pool.CODES], None
+    qmax = (1 << (min(kv_bits, 8) - 1)) - 1
+    shape = (1, n_pool, bs, kv, kv_pool.store_dh(dh, kv_bits))
+    mk = lambda: jnp.asarray(
+        rng.integers(-qmax, qmax + 1, shape).astype(np.int8))
+    ms = lambda: jnp.asarray(
+        rng.uniform(1e-3, 1e-1, shape[:4] + (1,)).astype(np.float32))
+    pool = kv_pool.from_heads(mk(), ms(), mk(), ms())
+    return pool[kv_pool.CODES], pool[kv_pool.SCALES]
+
+
 def autotune_kv_block_size(*, b: int, kv: int, g: int, dh: int, s_max: int,
                            kv_bits: int = 8, candidates=(16, 32, 64, 128),
                            iters: int = 2, interpret: bool | None = None,
@@ -713,28 +736,16 @@ def autotune_kv_block_size(*, b: int, kv: int, g: int, dh: int, s_max: int,
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, kv, g, dh)).astype(np.float32))
     pos = jnp.full((b,), s_max - 1, jnp.int32)
-    quant = kv_bits < 16
-    qmax = (1 << (min(kv_bits, 8) - 1)) - 1 if quant else 0
-    dh_store = dh // 2 if kv_bits == 4 else dh
 
     def measure(block):
         bs = block[2]
         nb = s_max // bs
         n_pool = b * nb + 1
-        if quant:
-            mk = lambda: jnp.asarray(rng.integers(
-                -qmax, qmax + 1, (n_pool, bs, kv, dh_store)).astype(np.int8))
-            ms = lambda: jnp.asarray(rng.uniform(
-                1e-3, 1e-1, (n_pool, bs, kv, 1)).astype(np.float32))
-            kp, ksc, vp, vsc = mk(), ms(), mk(), ms()
-        else:
-            mk = lambda: jnp.asarray(
-                rng.normal(size=(n_pool, bs, kv, dh)).astype(np.float32))
-            kp, vp, ksc, vsc = mk(), mk(), None, None
+        kvp, kvs = _random_pool(rng, n_pool, bs, kv, dh, kv_bits)
         pt = jnp.asarray(
             rng.permutation(b * nb).reshape(b, nb).astype(np.int32) + 1)
         return tuning.time_fn(
-            lambda: kernel(q, kp, ksc, vp, vsc, pt, pos, kv_bits=kv_bits,
+            lambda: kernel(q, kvp, kvs, pt, pos, 0, kv_bits=kv_bits,
                            interpret=interpret), iters=iters)
 
     cands = [(1, dh, bs) for bs in candidates if s_max % bs == 0] \
@@ -781,53 +792,55 @@ def _wo_is_float(wo_p: dict, pcfg: PrecisionConfig) -> bool:
 
 
 @register_attention(ATTN_FUSED, (16, 8, 4), BACKEND_XLA)
-def _fused_decode_xla(q, k, ks, v, vs, extras, *, kv_bits, dtype, block,
+def _fused_decode_xla(q, kv, kvs, extras, *, kv_bits, dtype, block,
                       interpret):
     """Reference composition: gather live rows -> paged-attention oracle ->
     the model's wo projection.  Per-row numerics (attention per slot, per-row
     activation scales) make the gathered sub-batch bit-identical to the
     padded full-batch layer math."""
     from .paged_attention import paged_attention_ref
-    page_table, pos, slot_map, wo_p, pcfg = extras
+    page_table, pos, slot_map, wo_p, pcfg, layer = extras
     ql = q[slot_map]
-    attn = paged_attention_ref(ql, k, ks, v, vs, page_table[slot_map],
-                               jnp.asarray(pos)[slot_map], kv_bits=kv_bits,
-                               out_dtype=dtype)
+    attn = paged_attention_ref(ql, kv, kvs, page_table[slot_map],
+                               jnp.asarray(pos)[slot_map], layer,
+                               kv_bits=kv_bits, out_dtype=dtype)
     flat = attn.reshape(ql.shape[0], 1, -1)              # (L, 1, KV*G*Dh)
     return _project_wo(flat, wo_p, pcfg, dtype)          # (L, 1, D)
 
 
 @register_attention(ATTN_FUSED, (16, 8, 4), BACKEND_PALLAS)
-def _fused_decode_pallas(q, k, ks, v, vs, extras, *, kv_bits, dtype, block,
+def _fused_decode_pallas(q, kv, kvs, extras, *, kv_bits, dtype, block,
                          interpret):
     """Single-dispatch fused kernel for float ``wo``; quantized ``wo``
     configs compose the paged-attention kernel with the engine's own
     ``qmatmul`` epilogue instead (the per-row requant epilogue must never
     fork numerics from the registry matmul the rest of the model uses)."""
-    page_table, pos, slot_map, wo_p, pcfg = extras
+    page_table, pos, slot_map, wo_p, pcfg, layer = extras
     if not _wo_is_float(wo_p, pcfg):
         attend = resolve_attention(ATTN_PAGED, kv_bits, BACKEND_PALLAS)
         ql = q[slot_map]
-        attn = attend(ql, k, ks, v, vs,
-                      (page_table[slot_map], jnp.asarray(pos)[slot_map]),
+        attn = attend(ql, kv, kvs,
+                      (page_table[slot_map], jnp.asarray(pos)[slot_map],
+                       layer),
                       kv_bits=kv_bits, dtype=dtype, block=None,
                       interpret=interpret)
         flat = attn.reshape(ql.shape[0], 1, -1)
         return _project_wo(flat, wo_p, pcfg, dtype)
     from .decode_fused import fused_decode
-    out = fused_decode(q, k, ks, v, vs, page_table, pos, slot_map,
-                       wo_p["qw"], kv_bits=kv_bits, interpret=interpret)
+    out = fused_decode(q, kv, kvs, page_table, pos, slot_map, wo_p["qw"],
+                       layer, kv_bits=kv_bits, interpret=interpret)
     return out[:, None, :].astype(dtype)                 # (L, 1, D)
 
 
-def fused_paged_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
-                       slot_map, wo_p: dict, pcfg: PrecisionConfig, *,
+def fused_paged_decode(q, kv_pool, kv_scale, page_table, pos, slot_map,
+                       wo_p: dict, pcfg: PrecisionConfig, *, layer,
                        kv_bits: int = 8, dtype=jnp.float32,
                        backend: str | None = None,
                        interpret: bool | None = None):
-    """Fused ragged decode step via the registry: paged attention over the
-    **live slots only** (``slot_map`` (L,) int32 into the padded batch) with
-    the wo output projection folded in.  Returns the padded (B, 1, D)
+    """Fused ragged decode step via the registry: paged attention over
+    layer ``layer`` of the stacked pool for the **live slots only**
+    (``slot_map`` (L,) int32 into the padded batch) with the wo output
+    projection folded in.  Returns the padded (B, 1, D)
     projected output — live rows carry the projection, dead rows are exact
     zeros (their residual stream is ignored by the batcher anyway).
 
@@ -846,8 +859,8 @@ def fused_paged_decode(q, k_pool, k_scale, v_pool, v_scale, page_table, pos,
                      a_bits=kv_bits, w_bits=8,
                      m_rows=int(slot_map.shape[0]),
                      a_scale_shape=None, block=None)
-    compact = fn(q, k_pool, k_scale, v_pool, v_scale,
-                 (page_table, pos, slot_map, wo_p, pcfg),
+    compact = fn(q, kv_pool, kv_scale,
+                 (page_table, pos, slot_map, wo_p, pcfg, layer),
                  kv_bits=kv_bits, dtype=dtype, block=None,
                  interpret=interpret)                    # (L, 1, D)
     d = compact.shape[-1]
@@ -873,28 +886,16 @@ def autotune_fused_block_size(*, b: int, kv: int, g: int, dh: int, d: int,
         rng.normal(size=(kv * g * dh, d)).astype(np.float32) * dh ** -0.5)
     slot_map = jnp.arange(b, dtype=jnp.int32)
     pos = jnp.full((b,), s_max - 1, jnp.int32)
-    quant = kv_bits < 16
-    qmax = (1 << (min(kv_bits, 8) - 1)) - 1 if quant else 0
-    dh_store = dh // 2 if kv_bits == 4 else dh
 
     def measure(block):
         bs = block[2]
         nb = s_max // bs
         n_pool = b * nb + 1
-        if quant:
-            mk = lambda: jnp.asarray(rng.integers(
-                -qmax, qmax + 1, (n_pool, bs, kv, dh_store)).astype(np.int8))
-            ms = lambda: jnp.asarray(rng.uniform(
-                1e-3, 1e-1, (n_pool, bs, kv, 1)).astype(np.float32))
-            kp, ksc, vp, vsc = mk(), ms(), mk(), ms()
-        else:
-            mk = lambda: jnp.asarray(
-                rng.normal(size=(n_pool, bs, kv, dh)).astype(np.float32))
-            kp, vp, ksc, vsc = mk(), mk(), None, None
+        kvp, kvs = _random_pool(rng, n_pool, bs, kv, dh, kv_bits)
         pt = jnp.asarray(
             rng.permutation(b * nb).reshape(b, nb).astype(np.int32) + 1)
         return tuning.time_fn(
-            lambda: kernel(q, kp, ksc, vp, vsc, pt, pos, slot_map, wo,
+            lambda: kernel(q, kvp, kvs, pt, pos, slot_map, wo, 0,
                            kv_bits=kv_bits, interpret=interpret),
             iters=iters)
 

@@ -28,7 +28,7 @@ from repro.core.precision import (
 )
 from repro.core.packing import pack_nibbles, unpack_nibbles
 from repro.core.quantize import act_fake_quant, weight_fake_quant
-from repro.kernels import engine
+from repro.kernels import engine, kv_pool
 
 from .config import ModelConfig
 
@@ -343,21 +343,22 @@ def attn_apply(p, x, cfg: ModelConfig, positions, *, local: bool,
 
 
 def attn_apply_paged(p, x, cfg: ModelConfig, positions, *, local: bool,
-                     pool, page_table, kv_bits: int, slot_map=None,
-                     fused: bool = True):
+                     pool, page_table, kv_bits: int, layer,
+                     slot_map=None, fused: bool = True):
     """Attention over a block-paged KV pool (runtime.kvcache) instead of a
     per-slot dense cache.
 
-    pool: one layer's block storage ``{"k","v"[,"ks","vs"]}`` with leaves
-    (NB, bs, KV, Dh') — physical blocks shared by every request; block 0 is
-    the reserved null/scratch block.  page_table: (B, n_blocks) int32 mapping
-    each sequence's logical block j to its physical block.  positions:
-    (B, Sq) query positions — Sq > 1 is a B=1 prefill-chunk append, Sq == 1
-    the batched decode step; both write the chunk/token KV into the owning
-    blocks (``positions // bs`` -> page-table row -> physical block) and
-    attend over the gathered (B, n_blocks*bs) dense view with the causal
-    position mask, so the math — and, for kv_bits=16, the bits — match the
-    dense cache path exactly.
+    pool: this layer position's block storage as ``repro.kernels.kv_pool``
+    lays it out, stacked over periods (``layer`` picks the period) —
+    physical blocks shared by every request; block 0 is the reserved
+    null/scratch block.  page_table: (B, n_blocks) int32
+    mapping each sequence's logical block j to its physical block.
+    positions: (B, Sq) query positions — Sq > 1 is a B=1 prefill-chunk
+    append, Sq == 1 the batched decode step; both write the chunk/token KV
+    rows into the owning blocks (``positions // bs`` -> page-table row ->
+    physical block) in place, and attend over the (B, n_blocks*bs) page-table
+    view with the causal position mask, so the math — and, for kv_bits=16,
+    the bits — match the dense cache path exactly.
 
     Out-of-range positions (bucket padding past the pool view) and retired
     slots (their page-table rows are zeroed) deflect writes to the null
@@ -371,7 +372,9 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, *, local: bool,
     """
     b = x.shape[0]
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.dh
-    nb, bs = page_table.shape[1], pool["k"].shape[1]
+    dh_s = kv_pool.store_dh(dh, kv_bits)
+    nb = page_table.shape[1]
+    bs = kv_pool.block_positions(pool[kv_pool.CODES], kvh, dh_s)
     s_pad = nb * bs
     xn = rmsnorm(p["norm"], x, cfg.norm_eps)
     q = qlinear_apply(p["wq"], xn, cfg).reshape(b, -1, h, dh)
@@ -387,18 +390,14 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, *, local: bool,
     phys = jnp.take_along_axis(page_table.astype(jnp.int32), lb, axis=1)
     phys = jnp.where(pos < s_pad, phys, 0)                     # OOB -> null
     off = pos % bs
-    flat = lambda t: t.reshape(b * sq, *t.shape[2:])
-    pi, oi = phys.reshape(-1), off.reshape(-1)
-
-    def write(buf, upd):
-        return buf.at[pi, oi].set(flat(upd).astype(buf.dtype))
-
+    flat = lambda t: None if t is None else t.reshape(b * sq, *t.shape[2:])
     if kv_bits < 16:
         kq, ks, vq, vs = _kv_quantize(k, v, kv_bits)
-        new = {"k": write(pool["k"], kq), "v": write(pool["v"], vq),
-               "ks": write(pool["ks"], ks), "vs": write(pool["vs"], vs)}
     else:
-        new = {"k": write(pool["k"], k), "v": write(pool["v"], v)}
+        kq, ks, vq, vs = k, None, v, None
+    new = kv_pool.write(pool, layer, phys.reshape(-1), off.reshape(-1),
+                        flat(kq), flat(ks), flat(vq), flat(vs))
+    codes, scales = new[kv_pool.CODES], new.get(kv_pool.SCALES)
 
     if sq == 1 and not local and cfg.attn_softcap <= 0:
         # batched decode: engine-dispatched paged attention (page-table
@@ -411,29 +410,26 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, *, local: bool,
             # (their residual stream is never emitted)
             pcfg = signed(get_precision(cfg.precision))
             out = engine.fused_paged_decode(
-                q4, new["k"], new.get("ks"), new["v"], new.get("vs"),
-                page_table.astype(jnp.int32), pos[:, 0], slot_map, p["wo"],
-                pcfg, kv_bits=kv_bits, dtype=x.dtype)
+                q4, codes, scales, page_table.astype(jnp.int32), pos[:, 0],
+                slot_map, p["wo"], pcfg, layer=layer, kv_bits=kv_bits,
+                dtype=x.dtype)
             if "post_norm" in p:
                 out = rmsnorm(p["post_norm"], out, cfg.norm_eps)
             return out, new
         out = engine.paged_attention(
-            q4, new["k"], new.get("ks"), new["v"], new.get("vs"),
-            page_table.astype(jnp.int32), pos[:, 0], kv_bits=kv_bits,
-            dtype=x.dtype)
+            q4, codes, scales, page_table.astype(jnp.int32), pos[:, 0],
+            layer=layer, kv_bits=kv_bits, dtype=x.dtype)
         out = out.reshape(b, 1, h * dh)
     else:
         # prefill-chunk append (or local/softcap attention): attend over the
         # gathered dense (B, s_pad) page-table view
-        from repro.kernels.paged_attention import gather_pool
-        gather = lambda leaf: gather_pool(leaf, page_table)
+        kg, ksg, vg, vsg = kv_pool.gather(codes, scales, page_table, layer,
+                                          kvh, dh_s)
         if kv_bits < 16:
-            kk = _kv_dequant(gather(new["k"]), gather(new["ks"]), x.dtype,
-                             kv_bits)
-            vv = _kv_dequant(gather(new["v"]), gather(new["vs"]), x.dtype,
-                             kv_bits)
+            kk = _kv_dequant(kg, ksg, x.dtype, kv_bits)
+            vv = _kv_dequant(vg, vsg, x.dtype, kv_bits)
         else:
-            kk, vv = gather(new["k"]), gather(new["v"])
+            kk, vv = kg, vg
         j = jnp.arange(s_pad)[None, None, :]                   # (1,1,S)
         qpos = pos[:, :, None]                                 # (B,Sq,1)
         mask = (j <= qpos)[:, None]                            # (B,1,Sq,S)
@@ -450,23 +446,11 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, *, local: bool,
 def make_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
                  kv_bits: int, stacked: int = None):
     """Block-pool pytree for one attention layer (or stacked leading dim):
-    ``num_blocks`` physical blocks of ``block_size`` positions each.  Block 0
-    is reserved as the null/scratch block (never allocated)."""
-    kvh, dh = cfg.n_kv_heads, cfg.dh
-    lead = (stacked,) if stacked else ()
-    if kv_bits < 16:
-        dh_store = dh // 2 if kv_bits == 4 else dh
-        return {
-            "k": jnp.zeros(lead + (num_blocks, block_size, kvh, dh_store), jnp.int8),
-            "v": jnp.zeros(lead + (num_blocks, block_size, kvh, dh_store), jnp.int8),
-            "ks": jnp.full(lead + (num_blocks, block_size, kvh, 1), 1e-6, jnp.float32),
-            "vs": jnp.full(lead + (num_blocks, block_size, kvh, 1), 1e-6, jnp.float32),
-        }
-    dt = jnp.dtype(cfg.dtype)
-    return {
-        "k": jnp.zeros(lead + (num_blocks, block_size, kvh, dh), dt),
-        "v": jnp.zeros(lead + (num_blocks, block_size, kvh, dh), dt),
-    }
+    ``num_blocks`` physical blocks of ``block_size`` positions each, laid out
+    as ``repro.kernels.kv_pool`` defines.  Block 0 is reserved as the
+    null/scratch block (never allocated)."""
+    return kv_pool.make(num_blocks, block_size, cfg.n_kv_heads, cfg.dh,
+                        kv_bits, cfg.dtype, stacked)
 
 
 def make_kv_cache(cfg: ModelConfig, b: int, s_max: int, stacked: int = None):

@@ -174,45 +174,66 @@ def make_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
               kv_bits: int, mesh=None):
     """Stacked per-period block-pool pytree for the paged KV cache
     (runtime.kvcache): every attention layer gets ``num_blocks`` physical
-    blocks of ``block_size`` positions (block 0 reserved as null).  Requires
-    an attention-only stack — SSM state has no sequence dim to page.
+    blocks of ``block_size`` positions (block 0 reserved as null), laid out
+    as ``repro.kernels.kv_pool`` defines.  Requires an attention-only stack —
+    SSM state has no sequence dim to page.
 
-    With ``mesh``, leaves are placed under ``parallel.sharding.pool_specs``:
+    The leaves are made on the device in the pinned row-major layout the
+    paged kernels read (:func:`pool_formats`), so no program relays them.
+    With ``mesh``, they are placed under ``parallel.sharding.pool_specs``:
     KV heads shard over 'model' when they divide; the block and in-block
     position dims always stay local to a shard (appends are scatters at
     dynamic positions — the shard-local append rule)."""
     assert all(m.startswith("attn") for m in cfg.layer_pattern), \
         f"{cfg.name}: paged KV cache needs an attention-only stack"
-    per = {f"layer_{i}": L.make_kv_pool(cfg, num_blocks, block_size, kv_bits,
-                                        stacked=cfg.n_periods)
-           for i in range(cfg.period)}
-    if mesh is not None:
-        from repro.parallel.sharding import named_shardings, pool_specs
-        per = jax.device_put(per, named_shardings(
-            mesh, pool_specs(per, cfg, mesh)))
-    return per
+    make = lambda: {f"layer_{i}": L.make_kv_pool(cfg, num_blocks, block_size,
+                                                 kv_bits,
+                                                 stacked=cfg.n_periods)
+                    for i in range(cfg.period)}
+    return jax.jit(make, out_shardings=pool_formats(
+        jax.eval_shape(make), cfg, mesh))()
+
+
+def pool_formats(pool, cfg: ModelConfig, mesh=None):
+    """The pool's pinned layout and sharding, leaf by leaf: what
+    :func:`make_pool` places it in, and what every program that takes the
+    pool declares for its argument and result.  ``pool`` may be shapes."""
+    from repro.kernels import kv_pool
+    if mesh is None:
+        from jax.sharding import SingleDeviceSharding
+        return kv_pool.formats(pool, SingleDeviceSharding(jax.devices()[0]))
+    from repro.parallel.sharding import named_shardings, pool_specs
+    return kv_pool.formats(pool, named_shardings(mesh,
+                                                 pool_specs(pool, cfg, mesh)))
 
 
 def _paged_scan(params, x, cfg: ModelConfig, positions, pool, page_table,
                 kv_bits: int, slot_map=None, fused: bool = False):
-    def body(x, scanned):
-        pp, pool_p = scanned
-        new_pool_p = {}
+    """The layer loop over a paged pool.  The pool rides in the carry, whole:
+    each layer writes its new rows into the stacked leaves in place and the
+    kernels read its blocks through the layer index, so no layer of the pool
+    is sliced out or written back."""
+    def body(carry, scanned):
+        x, pool = carry
+        pp, layer = scanned
+        pool = dict(pool)
         for i, (mixer, ffn) in enumerate(zip(cfg.layer_pattern, cfg.ffn_pattern)):
             lp = pp[f"layer_{i}"]
-            out, new_pool_p[f"layer_{i}"] = L.attn_apply_paged(
+            out, pool[f"layer_{i}"] = L.attn_apply_paged(
                 lp["attn"], x, cfg, positions, local=(mixer == "attn_local"),
-                pool=pool_p[f"layer_{i}"], page_table=page_table,
-                kv_bits=kv_bits, slot_map=slot_map, fused=fused)
+                pool=pool[f"layer_{i}"], page_table=page_table,
+                kv_bits=kv_bits, layer=layer, slot_map=slot_map, fused=fused)
             x = x + out
             if ffn == "dense":
                 x = x + L.ffn_apply(lp["ffn"], x, cfg)
             elif ffn == "moe":
                 out, _ = L.moe_apply(lp["moe"], x, cfg)
                 x = x + out
-        return x, new_pool_p
+        return (x, pool), None
 
-    return jax.lax.scan(body, x, (params["blocks"], pool))
+    layers = jnp.arange(cfg.n_periods, dtype=jnp.int32)
+    (x, pool), _ = jax.lax.scan(body, (x, pool), (params["blocks"], layers))
+    return x, pool
 
 
 def prefill_chunk_paged(params, tokens, pool, page_table, pos,

@@ -215,27 +215,27 @@ def cache_specs(cache, cfg: ModelConfig, mesh: Mesh, batch: int,
 
 
 def pool_specs(pool, cfg: ModelConfig, mesh: Mesh):
-    """Paged KV block-pool specs (runtime.kvcache): leaves are
-    (P?, NB, bs, KV, Dh') — KV heads shard over 'model' when they divide and
-    TP applies; the block (NB) and in-block position (bs) dims ALWAYS stay
-    local to a shard.  Appends scatter KV rows at dynamically computed
-    (block, offset) coordinates, so — like the dense serving cache's
-    sequence dim (``allow_sp=False``) — the paged dims must never be
-    partitioned; sharding the pool over data requires per-shard pools and
-    page tables (open item)."""
+    """Paged KV block-pool specs (runtime.kvcache), for the layout
+    ``repro.kernels.kv_pool`` defines: leaves (P?, NB, rows, lanes) whose
+    lanes hold whole KV heads one after another (keys beside values, scales
+    alike), so the lane dim shards over 'model' in whole heads when the KV
+    heads divide and TP applies.  The block (NB) and in-block position (bs)
+    dims ALWAYS stay local to a shard.  Appends scatter KV rows at
+    dynamically computed (block, offset) coordinates, so — like the dense
+    serving cache's sequence dim (``allow_sp=False``) — the paged dims must
+    never be partitioned; sharding the pool over data requires per-shard
+    pools and page tables (open item)."""
+    from repro.kernels import kv_pool
     tp = _axis(mesh, "model")
     kv_ok = (not pure_dp(cfg, mesh)) and \
         (_div(cfg.n_kv_heads, tp) if cfg.n_kv_heads else False)
 
     def spec(path, leaf):
         keys = [k.key for k in path if hasattr(k, "key")]
-        rank = len(leaf.shape)
-        leafname = keys[-1] if keys else ""
-        if leafname in ("k", "v", "ks", "vs"):
-            lead = rank - 4                     # (P?, NB, bs, KV, Dh')
-            kvspec = "model" if kv_ok and _div(leaf.shape[lead + 2], tp) else None
-            return P(*(None,) * lead, None, None, kvspec, None)
-        return P(*(None,) * rank)
+        spec = [None] * len(leaf.shape)
+        if kv_ok and keys and keys[-1] in (kv_pool.CODES, kv_pool.SCALES):
+            spec[kv_pool.LANE_DIM] = "model"
+        return P(*spec)
 
     return jax.tree_util.tree_map_with_path(spec, pool)
 

@@ -95,24 +95,33 @@ def _select_paged(logits, greedy, slot_map, tok, pos, nout,
     return tok2[:, 0], tok2, pos2, nout2
 
 
-def paged_block_bytes(cfg, block_size: int, kv_bits: int) -> int:
-    """HBM bytes one physical block costs across the whole layer stack —
-    the denominator of the effective-capacity claim."""
-    kvh, dh = cfg.n_kv_heads, cfg.dh
-    n_attn = sum(1 for m in cfg.layer_pattern if m.startswith("attn")) \
+def _attn_layers(cfg) -> int:
+    return sum(1 for m in cfg.layer_pattern if m.startswith("attn")) \
         * cfg.n_periods
-    if kv_bits < 16:
-        dh_store = dh // 2 if kv_bits == 4 else dh
-        per_layer = 2 * block_size * kvh * (dh_store + 4)    # codes + f32 scale
-    else:
-        per_layer = 2 * block_size * kvh * dh * jnp.dtype(cfg.dtype).itemsize
-    return per_layer * n_attn
+
+
+def paged_block_bytes(cfg, block_size: int, kv_bits: int) -> int:
+    """HBM bytes one physical block costs across the whole layer stack, as
+    the pool is laid out on the TPU (``repro.kernels.kv_pool``: lanes pad to
+    128, rows to the tile height; a block's codes tile and its share of a
+    scale tile) — the denominator of the effective-capacity claim."""
+    from repro.kernels import kv_pool
+    tile = kv_pool.scale_tile_blocks(block_size, cfg.n_kv_heads)
+    return _attn_layers(cfg) * kv_pool.pool_bytes(
+        tile, block_size, cfg.n_kv_heads, cfg.dh, kv_bits, cfg.dtype) // tile
 
 
 def paged_capacity_blocks(cfg, pool_bytes: int, block_size: int,
                           kv_bits: int) -> int:
-    """Allocatable blocks (excluding the null block) a byte budget buys."""
-    return max(pool_bytes // paged_block_bytes(cfg, block_size, kv_bits) - 1, 0)
+    """Allocatable blocks (excluding the null block) a byte budget buys: the
+    most blocks whose pool, laid out, fits the budget."""
+    from repro.kernels import kv_pool
+    nb = pool_bytes // paged_block_bytes(cfg, block_size, kv_bits)
+    while nb > 0 and _attn_layers(cfg) * kv_pool.pool_bytes(
+            nb, block_size, cfg.n_kv_heads, cfg.dh, kv_bits,
+            cfg.dtype) > pool_bytes:
+        nb -= 1
+    return max(nb - 1, 0)
 
 
 class PagedBatcher(ContinuousBatcher):
@@ -285,14 +294,25 @@ class PagedBatcher(ContinuousBatcher):
             return logits, jnp.argmax(logits[:, 0], axis=-1), new_pool
 
         self._decode_fn = _decode_fn
-        self._select_paged = jax.jit(_select_paged)
+        # on one device every program states each argument's device: the
+        # pool programs pin the pool's layout, which commits what they
+        # return, and a jit that is left to infer shardings keys each
+        # argument on whether it is committed, so a token vector made on
+        # the device and one staged from the host would compile twice
+        self._one = None if mesh is not None else \
+            jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        self._select_paged = jax.jit(_select_paged, in_shardings=self._one)
         self._pt_dirty = True              # host page table changed
         self._pt_dev = None                # device-resident page table
         chunk_fn = lambda p, t, pool, pt, pos: \
             model.prefill_chunk_paged(p, t, pool, pt, pos, kv_bits)
+        # every program that takes the pool declares the layout make_pool
+        # placed it in, for its argument and its (donated) result, so no
+        # program relays the pool in or out
+        self._pool_fmt = tfm.pool_formats(self.pool, cfg, mesh)
         if mesh is None:
-            self._decode = jax.jit(_decode_fn, donate_argnums=(2,))
-            self._prefill_chunk = jax.jit(chunk_fn, donate_argnums=(2,))
+            self._decode = self._pool_jit(_decode_fn, 6, 3)
+            self._prefill_chunk = self._pool_jit(chunk_fn, 5, 2)
         else:
             # Sharded paged serving.  Pure-DP models dispatch shard_map-FIRST
             # with fully replicated specs: the pool cannot DP-shard (decode
@@ -309,10 +329,8 @@ class PagedBatcher(ContinuousBatcher):
             from jax.sharding import NamedSharding, PartitionSpec as P
             shd = self._shd
             rep = NamedSharding(mesh, P())
-            pool_tmpl = jax.eval_shape(
-                lambda: tfm.make_pool(cfg, num_blocks, bs, kv_bits))
-            pool_specs = shd.pool_specs(pool_tmpl, cfg, mesh)
-            pool_sh = shd.named_shardings(mesh, pool_specs)
+            pool_specs = shd.pool_specs(self.pool, cfg, mesh)
+            pool_sh = self._pool_fmt
             vspec = tuple(shd.logits_spec(cfg, mesh, 1))[-1]
             logits_sh = NamedSharding(mesh, P(None, None, vspec))
             decode_fn, jit_chunk_fn = _decode_fn, chunk_fn
@@ -385,8 +403,18 @@ class PagedBatcher(ContinuousBatcher):
                 p, t, pool, pt, pos_vec, kv_bits)
             return logits, jnp.argmax(logits, axis=-1), new_pool
 
-        self._draft_decode = jax.jit(_draft_fn, donate_argnums=(2,))
-        self._verify = jax.jit(_verify_fn, donate_argnums=(2,))
+        self._draft_decode = self._pool_jit(_draft_fn, 5, 2)
+        self._verify = self._pool_jit(_verify_fn, 5, 3)
+
+    def _pool_jit(self, fn, n_args: int, n_out: int):
+        """``fn(params, tokens, pool, ...)`` jitted on one device, the pool
+        donated and pinned to its layout as argument and as the last of
+        ``n_out`` results, every other argument and result on the device."""
+        one = self._one
+        ins = [one] * n_args
+        ins[2] = self._pool_fmt
+        return jax.jit(fn, donate_argnums=(2,), in_shardings=tuple(ins),
+                       out_shardings=(one,) * (n_out - 1) + (self._pool_fmt,))
 
     # ---------------------------------------------------------------- audit
     def audit_steps(self) -> list:

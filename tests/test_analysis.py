@@ -429,21 +429,24 @@ from repro.kernels.decode_fused import fused_decode
 rng6 = np.random.default_rng(6)
 B, KV, G, DH, BS, NB, D = 2, 1, 2, 4, 4, 2, 8
 q6 = jnp.asarray(rng6.standard_normal((B, KV, G, DH)).astype(np.float32))
+from repro.kernels import kv_pool
 kp6 = jnp.asarray(rng6.integers(
     -127, 128, (B * NB + 1, BS, KV, DH)).astype(np.int8))
 ks6 = jnp.ones((B * NB + 1, BS, KV, 1), jnp.float32)
+pool6 = kv_pool.from_heads(kp6[None], ks6[None], kp6[None], ks6[None])
+kv6, kvs6 = pool6[kv_pool.CODES], pool6[kv_pool.SCALES]
 pt6 = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB) + 1
 pos6 = jnp.array([3, 5], jnp.int32)
 sm6 = jnp.arange(B, dtype=jnp.int32)
 wo6 = jnp.asarray(rng6.standard_normal((KV * G * DH, D)).astype(np.float32))
 
 fused_fn = jax.jit(lambda q: fused_decode(
-    q, kp6, ks6, kp6, ks6, pt6, pos6, sm6, wo6, kv_bits=8, interpret=True))
+    q, kv6, kvs6, pt6, pos6, sm6, wo6, 0, kv_bits=8, interpret=True))
 good = StepSpec(name="fused-step", fn=fused_fn, args=(q6,), fused_layers=1)
 assert audit_step(good, rules=("fused_decode_single_dispatch",)) == []
 
 unfused_fn = jax.jit(lambda q: engine.paged_attention(
-    q, kp6, ks6, kp6, ks6, pt6, pos6, kv_bits=8, interpret=True))
+    q, kv6, kvs6, pt6, pos6, layer=0, kv_bits=8, interpret=True))
 bad = StepSpec(name="unfused-step", fn=unfused_fn, args=(q6,),
                fused_layers=1)
 fs = audit_step(bad, rules=("fused_decode_single_dispatch",))
@@ -453,8 +456,8 @@ assert "not on the fused path" in msgs, msgs
 assert "non-fused pallas_call" in msgs, msgs
 
 def sync_fn(q):
-    out = fused_decode(q, kp6, ks6, kp6, ks6, pt6, pos6, sm6, wo6,
-                       kv_bits=8, interpret=True)
+    out = fused_decode(q, kv6, kvs6, pt6, pos6, sm6, wo6, 0, kv_bits=8,
+                       interpret=True)
     probe = jax.pure_callback(
         lambda o: np.float32(0.0),
         jax.ShapeDtypeStruct((), jnp.float32), out)

@@ -8,12 +8,14 @@ import sys
 import tempfile
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.hlo import analyze_hlo_text, parse_hlo
+from repro.kernels import kv_pool
 from repro.models.config import ModelConfig
 from repro.parallel import sharding as sh
 
@@ -240,23 +242,62 @@ def test_named_shardings_tree():
 
 def test_pool_specs_never_shard_block_or_position_dims():
     """Paged KV pool: appends scatter at dynamic (block, offset) coordinates,
-    so only the KV-head dim may shard (over 'model', when TP applies)."""
+    so only the lane dim, which holds whole KV heads one after another, may
+    shard (over 'model', when TP applies)."""
     mesh = _mesh((4, 4))
     big = _cfg(2048, 8, 4, 128, 4096)          # TP applies, kv=4 divides 4
-    pool = {"layer_0": {"k": FakeLeaf((2, 10, 16, 4, 32)),
-                        "v": FakeLeaf((2, 10, 16, 4, 32)),
-                        "ks": FakeLeaf((2, 10, 16, 4, 1)),
-                        "vs": FakeLeaf((2, 10, 16, 4, 1))}}
+    pool = {"layer_0": {kv_pool.CODES: FakeLeaf((2, 10, 16, 2 * 4 * 32)),
+                        kv_pool.SCALES: FakeLeaf((2, 16, 2 * 4 * 16))}}
     specs = sh.pool_specs(pool, big, mesh)
-    for leaf in specs["layer_0"].values():
-        t = tuple(leaf)
-        assert t[:3] == (None, None, None)     # periods, blocks, positions
-        assert t[3] == "model" and t[4] is None
+    assert tuple(specs["layer_0"][kv_pool.CODES]) == (None, None, None,
+                                                      "model")
+    assert tuple(specs["layer_0"][kv_pool.SCALES]) == (None, None, "model")
     # misaligned KV heads replicate; pure-DP models always replicate
     odd = _cfg(2048, 9, 3, 128, 4096)
-    assert tuple(sh.pool_specs(pool, odd, mesh)["layer_0"]["k"]) == (None,) * 5
     small = _cfg(576, 9, 3, 1536, 4096)
-    assert tuple(sh.pool_specs(pool, small, mesh)["layer_0"]["k"]) == (None,) * 5
+    for cfg in (odd, small):
+        for name, leaf in sh.pool_specs(pool, cfg, mesh)["layer_0"].items():
+            assert tuple(leaf) == (None,) * len(pool["layer_0"][name].shape)
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_pool_lane_shards_hold_whole_heads(kv_bits, tp):
+    """An even split of the pool's lane dim (what ``pool_specs`` asks of the
+    'model' axis) gives shard ``i`` exactly the pool of KV heads
+    ``[i*KV/tp, (i+1)*KV/tp)``: read as the kernels read a pool of that many
+    heads, its keys, values and scales, nothing of another head's, at every
+    position of every block and layer (rows that pack several positions
+    included: at these widths kv16 and kv8 pack 2 a row, kv4 4)."""
+    kv, dh, bs, nb = 4, 8, 16, 3
+    dh_s = kv_pool.store_dh(dh, kv_bits)
+    rng = np.random.default_rng(kv_bits + tp)
+    heads = lambda last: jnp.asarray(rng.standard_normal(
+        (2, nb, bs, kv, last)).astype(np.float32))
+    k, v = heads(dh_s), heads(dh_s)
+    ks, vs = (None, None) if kv_bits == 16 else (heads(1), heads(1))
+    whole = kv_pool.from_heads(k, ks, v, vs)
+    table = jnp.arange(nb, dtype=jnp.int32)[None]
+    per = kv // tp
+    for i in range(tp):
+        h = slice(i * per, (i + 1) * per)
+        shard = {}
+        for name, leaf in whole.items():
+            width = leaf.shape[kv_pool.LANE_DIM] // tp
+            shard[name] = leaf[..., i * width:(i + 1) * width]
+        for layer in range(2):
+            got = kv_pool.gather(shard[kv_pool.CODES],
+                                 shard.get(kv_pool.SCALES), table, layer,
+                                 per, dh_s)
+            want = [None if x is None else
+                    x[layer, :, :, h].reshape(1, nb * bs, per, -1)
+                    for x in (k, ks, v, vs)]
+            for name, g, w in zip(("k", "ks", "v", "vs"), got, want):
+                assert (g is None) == (w is None), name
+                if w is not None:
+                    np.testing.assert_array_equal(
+                        np.asarray(g), np.asarray(w),
+                        err_msg=f"{name} shard {i} layer {layer}")
 
 
 # ---------------------------------------------------------------------------

@@ -154,6 +154,8 @@ def test_radix_evict_cascades_to_parents():
 # capacity math (the kv_bits -> effective-capacity claim)
 # ---------------------------------------------------------------------------
 def test_quantized_blocks_at_least_double_capacity():
+    from repro.kernels import kv_pool
+    from repro.models import transformer as tfm
     cfg, _, _ = _setup()
     budget = 1 << 20
     cap16 = paged_capacity_blocks(cfg, budget, 16, 16)
@@ -161,13 +163,23 @@ def test_quantized_blocks_at_least_double_capacity():
     cap4 = paged_capacity_blocks(cfg, budget, 16, 4)
     # smoke cfg serves fp32 -> int8 codes (+ scale overhead) give >= 2x
     assert cap8 >= 2 * cap16, (cap8, cap16)
-    assert cap4 > cap8
-    # block-bytes math agrees with the real device pool
-    from repro.models import transformer as tfm
-    for bits in (16, 8, 4):
-        pool = tfm.make_pool(cfg, 4, 16, bits)
-        nbytes = sum(l.nbytes for l in jax.tree_util.tree_leaves(pool))
-        assert nbytes == 4 * paged_block_bytes(cfg, 16, bits), bits
+    assert cap4 > cap8, (cap4, cap8)
+    # and at published widths, where smollm-135m's kv4 rows (192 lanes)
+    # pack two positions a stored row
+    wide = dataclasses.replace(get_config("smollm-135m"), dtype="float32")
+    assert paged_capacity_blocks(wide, budget, 16, 4) > \
+        paged_capacity_blocks(wide, budget, 16, 8)
+    # block-bytes math agrees with the pool as the TPU lays it out, and the
+    # pool a budget buys fits that budget
+    laid_out = lambda pool: sum(kv_pool.laid_out_bytes(leaf.shape, leaf.dtype)
+                                for leaf in jax.tree_util.tree_leaves(pool))
+    tile = kv_pool.scale_tile_blocks(16, cfg.n_kv_heads)
+    for bits, cap in ((16, cap16), (8, cap8), (4, cap4)):
+        pool = tfm.make_pool(cfg, tile, 16, bits)
+        assert laid_out(pool) == tile * paged_block_bytes(cfg, 16, bits), bits
+        bought = jax.eval_shape(lambda: tfm.make_pool(cfg, cap + 1, 16, bits))
+        more = jax.eval_shape(lambda: tfm.make_pool(cfg, cap + 2, 16, bits))
+        assert laid_out(bought) <= budget < laid_out(more), bits
 
 
 def test_pool_bytes_constructor_sizes_the_pool():
@@ -217,6 +229,22 @@ def test_paged_quantized_matches_dense_quantized(kv_bits, block_size):
         ServingConfig(n_slots=2, s_max=S_MAX, chunk_size=4, kv_bits=kv_bits, block_size=block_size))
     got = _run(paged, prompts, max_new=5)
     assert got == want
+
+
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_paged_matches_dense_every_kv_width(kv_bits, fused):
+    """Over the pool as the kernels store it (keys beside values, a scale
+    row per block, carried whole through the layer loop), paged streams at
+    every KV width equal the dense batcher's, bitwise, with the fused decode
+    dispatch and without it."""
+    cfg, model, params = _setup()
+    prompts = [_prompt(3 + 2 * i, 7 + i, cfg.vocab) for i in range(3)]
+    want = _dense_memo(0 if kv_bits == 16 else kv_bits, prompts, 4, 2, 4)
+    paged = PagedBatcher(model, params, ServingConfig(
+        n_slots=2, s_max=S_MAX, chunk_size=4, kv_bits=kv_bits, block_size=4,
+        fused_decode=fused))
+    assert _run(paged, prompts, max_new=4) == want
 
 
 def test_prefix_hits_never_change_outputs():
@@ -512,7 +540,7 @@ _, base = serve(None)
 b_mp, got = serve(make_mesh(1, 8))
 assert got == base, (got, base)
 # the pool really is KV-head sharded over the model axis
-spec = tuple(b_mp.pool["layer_0"]["k"].sharding.spec)
+spec = tuple(b_mp.pool["layer_0"]["kv"].sharding.spec)
 assert "model" in spec, spec
 print("PAGED_TP_GOLDEN_OK")
 """

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import engine, tuning
+from repro.kernels import engine, kv_pool, tuning
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_serving_ref)
 from repro.kernels.paged_attention import paged_attention, paged_attention_ref
@@ -28,6 +28,16 @@ def _pool(nb, bs, kv, dh, kv_bits):
     return mk(), ms(), mk(), ms()
 
 
+def _stored(kp, ks, vp, vs, layers=False):
+    """Per-head (NB, bs, KV, Dh') leaves -> the pool's stored (codes,
+    scales) leaves (scales None at kv_bits 16), stacked over one layer; with
+    ``layers`` the leaves are stacked already, (L, NB, bs, KV, Dh')."""
+    lift = (lambda x: x) if layers else \
+        (lambda x: None if x is None else x[None])
+    pool = kv_pool.from_heads(lift(kp), lift(ks), lift(vp), lift(vs))
+    return pool[kv_pool.CODES], pool.get(kv_pool.SCALES)
+
+
 def _page_table(b, n_blocks, nb_pool):
     """Distinct physical blocks per (b, j) drawn from [1, nb_pool)."""
     ids = RNG.permutation(nb_pool - 1)[: b * n_blocks] + 1
@@ -39,6 +49,10 @@ def _page_table(b, n_blocks, nb_pool):
     (1, 4, 1, 128, 32, 4, 8),      # MQA-style grouping 1
     (3, 1, 8, 64, 16, 4, 16),      # float blocks
     (2, 2, 2, 64, 16, 8, 4),       # nibble-packed blocks
+    # stored rows of several positions (kv_pool.positions_per_row)
+    (2, 3, 3, 64, 16, 4, 4),       # smollm-135m's kv4 rows: 2 a row
+    (2, 2, 2, 16, 16, 4, 8),       # 2 a row
+    (2, 1, 2, 16, 16, 4, 16),      # float rows: 4 a row
 ])
 def test_paged_attention_kernel_matches_ref(b, kv, g, dh, bs, nblk, kv_bits):
     nb_pool = b * nblk + 3
@@ -46,9 +60,10 @@ def test_paged_attention_kernel_matches_ref(b, kv, g, dh, bs, nblk, kv_bits):
     kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, kv_bits)
     pt = _page_table(b, nblk, nb_pool)
     pos = jnp.asarray(RNG.integers(1, nblk * bs, (b,)).astype(np.int32))
-    got = paged_attention(q, kp, ks, vp, vs, pt, pos, kv_bits=kv_bits,
-                          interpret=True)
-    want = paged_attention_ref(q, kp, ks, vp, vs, pt, pos, kv_bits=kv_bits)
+    got = paged_attention(q, *_stored(kp, ks, vp, vs), pt, pos, 0,
+                          kv_bits=kv_bits, interpret=True)
+    want = paged_attention_ref(q, *_stored(kp, ks, vp, vs), pt, pos, 0,
+                               kv_bits=kv_bits)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -56,10 +71,9 @@ def test_paged_attention_kernel_matches_ref(b, kv, g, dh, bs, nblk, kv_bits):
 def _bf16_model_attention(q, kp, ks, vp, vs, pt, pos):
     """What a bf16 model's decode attention computes: K/V dequantized and
     rounded to bf16 (``layers._kv_dequant``), then f32 softmax attention."""
-    from repro.kernels.paged_attention import gather_pool
-    deq = lambda c, sc: (gather_pool(c, pt).astype(jnp.float32)
-                         * gather_pool(sc, pt)).astype(jnp.bfloat16
-                                                       ).astype(jnp.float32)
+    gather = lambda leaf: leaf[pt].reshape(pt.shape[0], -1, *leaf.shape[2:])
+    deq = lambda c, sc: (gather(c).astype(jnp.float32)
+                         * gather(sc)).astype(jnp.bfloat16).astype(jnp.float32)
     k, v = deq(kp, ks), deq(vp, vs)                     # (B, S, KV, Dh)
     dh = q.shape[-1]
     s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32), k) / dh ** 0.5
@@ -83,12 +97,13 @@ def test_paged_kernels_round_kv_to_bf16_model_dtype(kernel):
     pos = jnp.asarray([nblk * bs - 1, 21], np.int32)
     want = _bf16_model_attention(q, kp, ks, vp, vs, pt, pos)
     if kernel == "paged":
-        got = paged_attention(q, kp, ks, vp, vs, pt, pos, interpret=True)
+        got = paged_attention(q, *_stored(kp, ks, vp, vs), pt, pos, 0,
+                              interpret=True)
     else:
         wo = jnp.asarray(RNG.normal(size=(kv * g * dh, d))
                          ).astype(jnp.bfloat16)
-        got = fused_decode(q, kp, ks, vp, vs, pt, pos, jnp.arange(b), wo,
-                           interpret=True)
+        got = fused_decode(q, *_stored(kp, ks, vp, vs), pt, pos,
+                           jnp.arange(b), wo, 0, interpret=True)
         want = jnp.dot(want.astype(jnp.bfloat16).astype(jnp.float32)
                        .reshape(b, -1), wo.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -104,11 +119,14 @@ def test_paged_attention_unreferenced_blocks_are_invisible():
     kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, 8)
     pt = _page_table(b, nblk, nb_pool)
     pos = jnp.asarray([nblk * bs - 1, 7], np.int32)
-    out1 = paged_attention(q, kp, ks, vp, vs, pt, pos, interpret=True)
+    out1 = paged_attention(q, *_stored(kp, ks, vp, vs), pt, pos, 0,
+                           interpret=True)
     unref = sorted(set(range(nb_pool)) - set(np.asarray(pt).ravel().tolist()))
     kp2 = jnp.asarray(np.asarray(kp)).at[jnp.asarray(unref)].set(127)
     vp2 = jnp.asarray(np.asarray(vp)).at[jnp.asarray(unref)].set(127)
-    out2 = paged_attention(q, kp2, ks, vp2, vs, pt, pos, interpret=True)
+    ks2 = ks.at[jnp.asarray(unref)].set(1.0)
+    out2 = paged_attention(q, *_stored(kp2, ks2, vp2, vs), pt, pos, 0,
+                           interpret=True)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
 
@@ -119,9 +137,11 @@ def test_paged_attention_masks_past_pos():
     kp, ks, vp, vs = _pool(nblk + 1, bs, kv, dh, 8)
     pt = jnp.asarray([[1, 2, 3, 4]], np.int32)
     pos = jnp.int32(bs - 1)                       # only block 1 visible
-    out1 = paged_attention(q, kp, ks, vp, vs, pt, pos, interpret=True)
+    out1 = paged_attention(q, *_stored(kp, ks, vp, vs), pt, pos, 0,
+                           interpret=True)
     kp2 = jnp.asarray(np.asarray(kp)).at[2:].set(127)
-    out2 = paged_attention(q, kp2, ks, vp, vs, pt, pos, interpret=True)
+    out2 = paged_attention(q, *_stored(kp2, ks, vp, vs), pt, pos, 0,
+                           interpret=True)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
 
@@ -139,9 +159,10 @@ def test_paged_attention_dead_block_guard_is_identity():
         pt_long = _page_table(b, long_blocks, nb_pool)
         pt_live = pt_long[:, :live_blocks]
         pos = jnp.asarray([live_blocks * bs - 1, 5], np.int32)
-        out_long = paged_attention(q, kp, ks, vp, vs, pt_long, pos,
+        pool = _stored(kp, ks, vp, vs)
+        out_long = paged_attention(q, *pool, pt_long, pos, 0,
                                    kv_bits=kv_bits, interpret=True)
-        out_live = paged_attention(q, kp, ks, vp, vs, pt_live, pos,
+        out_live = paged_attention(q, *pool, pt_live, pos, 0,
                                    kv_bits=kv_bits, interpret=True)
         np.testing.assert_array_equal(np.asarray(out_long),
                                       np.asarray(out_live))
@@ -156,7 +177,7 @@ def test_paged_ref_equals_dense_gather():
     kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, 8)
     pt = _page_table(b, nblk, nb_pool)
     pos = jnp.asarray([13, 20], np.int32)
-    got = paged_attention_ref(q, kp, ks, vp, vs, pt, pos)
+    got = paged_attention_ref(q, *_stored(kp, ks, vp, vs), pt, pos, 0)
     gather = lambda leaf: leaf[pt].reshape(b, nblk * bs, *leaf.shape[2:])
     want = decode_attention_serving_ref(q, gather(kp), gather(ks),
                                         gather(vp), gather(vs), pos)
@@ -167,6 +188,126 @@ def test_paged_ref_equals_dense_gather():
 # ---------------------------------------------------------------------------
 # engine attention registry
 # ---------------------------------------------------------------------------
+def _layers(n_layers, nb, bs, kv, dh, kv_bits):
+    """``n_layers`` random per-head pools, stacked leaf by leaf."""
+    pools = [_pool(nb, bs, kv, dh, kv_bits) for _ in range(n_layers)]
+    return [None if leaves[0] is None else jnp.stack(leaves)
+            for leaves in zip(*pools)]
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_pool_write_matches_per_head_scatter(kv_bits):
+    """``kv_pool.write`` puts each update's codes at (layer, block, offset)
+    and its scales at the position's lanes of the block's scale row — as a
+    scatter into per-head leaves does — with several updates of one block
+    and of one stored row (a prefill chunk), the null block among them, and
+    every other layer, block and position left as it was; at one position a
+    stored row and at several (``bs`` 16 packs 4 a row at these widths)."""
+    n_layers, nb, kv, dh, layer = 3, 6, 2, 8, 1
+    for bs in (4, 16):
+        heads = _layers(n_layers, nb, bs, kv, dh, kv_bits)
+        phys = jnp.asarray([2, 2, 2, 5, 1, 0, 2], jnp.int32)
+        off = jnp.asarray([0, 1, bs - 1, 2, 0, 3, 2], jnp.int32)
+        rows = [leaf[0, :, 0][:phys.shape[0]] if leaf is not None else None
+                for leaf in _layers(1, 8, 1, kv, dh, kv_bits)]  # (N, KV, Dh')
+        got = kv_pool.write(kv_pool.from_heads(*heads), jnp.int32(layer),
+                            phys, off, *rows)
+        want = kv_pool.from_heads(*(
+            None if leaf is None else leaf.at[layer, phys, off].set(row)
+            for leaf, row in zip(heads, rows)))
+        assert got.keys() == want.keys()
+        for name in want:
+            assert got[name].shape == want[name].shape
+            np.testing.assert_array_equal(np.asarray(got[name]),
+                                          np.asarray(want[name]),
+                                          err_msg=f"{name} bs={bs}")
+    assert kv_pool.leaf_specs(nb, 16, kv, dh, 8, jnp.float32)[
+        kv_pool.CODES].shape == (nb, 4, 4 * 2 * kv * dh)
+
+
+@pytest.mark.parametrize("kv_bits", [8, 4])
+def test_pool_write_temp_is_linear_in_updates(kv_bits):
+    """A prefill chunk's row writes hold temporaries linear in its length:
+    at smollm-135m's widths the compiled write of 2048 positions holds at
+    most 5x the temp bytes of 512 (4x is linear, a merge over every pair
+    of updates' lanes would be 16x) and well under a GiB."""
+    kv, dh = 3, 64
+    dh_s = kv_pool.store_dh(dh, kv_bits)
+    pool = kv_pool.leaf_specs(1000, 16, kv, dh, kv_bits, jnp.bfloat16,
+                              stacked=4)
+    write = jax.jit(lambda pool, *a: kv_pool.write(pool, 1, *a),
+                    donate_argnums=0)
+
+    def temp(n):
+        i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+        codes = jax.ShapeDtypeStruct((n, kv, dh_s), jnp.int8)
+        scales = jax.ShapeDtypeStruct((n, kv, 1), jnp.float32)
+        compiled = write.lower(pool, i32, i32, codes, scales, codes,
+                               scales).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+    small, large = temp(512), temp(2048)
+    assert large <= 5 * small and large < 64 << 20, (small, large)
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_fused_kernel_matches_ref_on_packed_rows(kv_bits):
+    """The fused decode kernel over stored rows of several positions each
+    (``kv_pool.positions_per_row`` packs 2 or 4 at these widths) agrees
+    with its reference composition."""
+    from repro.kernels.decode_fused import fused_decode, fused_decode_ref
+    b, kv, g, dh, bs, nblk, d = 3, 2, 2, 16, 16, 3, 24
+    nb_pool = b * nblk + 1
+    kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, kv_bits)
+    pool = _stored(kp, ks, vp, vs)
+    assert pool[0].shape[-2] < bs
+    q = jnp.asarray(RNG.normal(size=(b, kv, g, dh)).astype(np.float32))
+    wo = jnp.asarray(RNG.normal(size=(kv * g * dh, d)).astype(np.float32))
+    pt = _page_table(b, nblk, nb_pool)
+    pos = jnp.asarray([nblk * bs - 1, 17, 3], np.int32)
+    sm = jnp.asarray([2, 0], jnp.int32)
+    got = fused_decode(q, *pool, pt, pos, sm, wo, 0, kv_bits=kv_bits,
+                       interpret=True)
+    want = fused_decode_ref(q, *pool, pt, pos, sm, wo, 0, kv_bits=kv_bits)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", ["paged", "fused"])
+@pytest.mark.parametrize("kv_bits", [16, 8, 4])
+def test_stacked_pool_layer_reads_equal_one_layer(kernel, kv_bits):
+    """The kernels and the reference read layer ``l`` of a stacked pool,
+    through the layer index, exactly as they read that layer's pool
+    alone."""
+    from repro.kernels.decode_fused import fused_decode
+    n_layers, b, kv, g, dh, bs, nblk = 3, 2, 2, 2, 16, 8, 3
+    nb_pool = b * nblk + 1
+    heads = _layers(n_layers, nb_pool, bs, kv, dh, kv_bits)
+    stacked = _stored(*heads, layers=True)
+    q = jnp.asarray(RNG.normal(size=(b, kv, g, dh)).astype(np.float32))
+    wo = jnp.asarray(RNG.normal(size=(kv * g * dh, 8)).astype(np.float32))
+    pt = _page_table(b, nblk, nb_pool)
+    pos = jnp.asarray([nblk * bs - 1, 10], np.int32)
+    sm = jnp.arange(b, dtype=jnp.int32)
+
+    def read(pool, layer):
+        if kernel == "paged":
+            return paged_attention(q, *pool, pt, pos, layer, kv_bits=kv_bits,
+                                   interpret=True)
+        return fused_decode(q, *pool, pt, pos, sm, wo, layer,
+                            kv_bits=kv_bits, interpret=True)
+    for layer in range(n_layers):
+        one = _stored(*(None if leaf is None else leaf[layer]
+                        for leaf in heads))
+        np.testing.assert_array_equal(
+            np.asarray(read(stacked, jnp.int32(layer))),
+            np.asarray(read(one, 0)), err_msg=f"layer {layer}")
+        np.testing.assert_array_equal(
+            np.asarray(paged_attention_ref(q, *stacked, pt, pos, layer,
+                                           kv_bits=kv_bits)),
+            np.asarray(paged_attention_ref(q, *one, pt, pos, 0,
+                                           kv_bits=kv_bits)))
+
+
 def test_attention_registry_resolution_and_fallback():
     ks = engine.available_attention_kernels()
     assert (engine.ATTN_DECODE, 8, engine.BACKEND_PALLAS) in ks
@@ -210,9 +351,10 @@ def test_engine_paged_attention_backends_agree():
         kp, ks, vp, vs = _pool(nb_pool, bs, kv, dh, kv_bits)
         pt = _page_table(b, nblk, nb_pool)
         pos = jnp.asarray([20, 40], np.int32)
-        xla = engine.paged_attention(q, kp, ks, vp, vs, pt, pos,
+        pool = _stored(kp, ks, vp, vs)
+        xla = engine.paged_attention(q, *pool, pt, pos, layer=0,
                                      kv_bits=kv_bits, backend="xla")
-        pal = engine.paged_attention(q, kp, ks, vp, vs, pt, pos,
+        pal = engine.paged_attention(q, *pool, pt, pos, layer=0,
                                      kv_bits=kv_bits, backend="pallas",
                                      interpret=True)
         np.testing.assert_allclose(np.asarray(xla), np.asarray(pal),
